@@ -990,6 +990,8 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown scenario(s) {unknown}; choose from "
                  f"{sorted(SCENARIOS)}")
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.trace:
         from repro.obs import enable_tracing
         enable_tracing()

@@ -63,7 +63,8 @@ import numpy as np
 from repro.core.api import LMBHost
 from repro.core.client import MemoryHandle
 from repro.core.metrics import Metrics, GLOBAL_METRICS
-from repro.core.offload import TierExecutor
+from repro.core.offload import (HostPool, TierExecutor, stack_pages,
+                                tier_of as array_tier)
 from repro.core.overlap import OverlapScheduler
 from repro.core.policy import EvictionPolicy, Prefetcher, make_policy
 from repro.core.pool import OutOfMemory
@@ -144,25 +145,21 @@ class LinkedBuffer:
         host.fm.on_repair(self._on_repair)
         # QoS link metering: every byte crossing to/from the LMB tier is
         # charged to this device's share of the expander link.  If the
-        # caller's executor carries a meter hook AND actually fires it
-        # (only on real host tiers — in pure modeling mode the executor
-        # can't tell LMB pools from device arrays), defer to it to avoid
+        # caller's executor carries a meter hook, defer to it to avoid
         # double-charging the same page move.  On a POOLED fabric the
         # buffer always meters itself: only it knows which expander backs
         # the touched chunk, while an executor hook is a bare meter(nbytes)
         # that would dump everything on the fallback link — so don't bind
         # an executor meter over a multi-expander FM.
         pooled = len(host.fm.healthy_expander_ids()) > 1
-        if (pooled and self.executor.meter is not None
-                and self.executor.real_host_tier):
+        if pooled and self.executor.meter is not None:
             raise ValueError(
                 f"{name}: an executor-level meter hook cannot attribute "
                 "transfers to an expander on a pooled fabric (and the "
                 "buffer's own per-block metering would double-charge); "
                 "construct the TierExecutor without meter= and let the "
                 "buffer meter")
-        self._meter_via_executor = (self.executor.meter is not None
-                                    and self.executor.real_host_tier)
+        self._meter_via_executor = self.executor.meter is not None
         self.link_wait_s = 0.0
 
         # pools
@@ -173,7 +170,7 @@ class LinkedBuffer:
 
         self._lmb_chunk_pages = lmb_chunk_pages
         self._lmb_scales: Dict[int, float] = {}   # slot -> absmax scale
-        self._lmb_pools: List[Optional[jax.Array]] = []  # None = reclaimed
+        self._lmb_pools: List[Optional[HostPool]] = []  # None = reclaimed
         #: per-chunk capability for the backing LMB allocation
         self._lmb_allocs: List[Optional[MemoryHandle]] = []
         #: per-expander free lists (LIFO): expander id -> free lmb slots.
@@ -225,6 +222,12 @@ class LinkedBuffer:
         public residency query (serving stats report how much admitted
         KV the LMB pool, not HBM, is carrying)."""
         return self._pages[page].tier
+
+    def lmb_memory_kinds(self) -> set:
+        """Memory kinds that hold the live LMB pools' pages: where the LMB
+        tier really sits (``{"pinned_host"}``), as JAX reports it."""
+        return {array_tier(p) for pool in self._lmb_pools
+                if pool is not None for p in pool.pages}
 
     # --------------------------------------------------------------- allocation
     def append_pages(self, n: int = 1) -> List[int]:
@@ -435,8 +438,8 @@ class LinkedBuffer:
         for chunk, idxs in self._runs_by_chunk(slots).items():
             offs = [slots[i] % self._lmb_chunk_pages for i in idxs]
             arr = self._lmb_read_run(chunk, offs)
-            for j, i in enumerate(idxs):
-                data[i] = arr[j]
+            for i, row in zip(idxs, arr):
+                data[i] = row
             charges.append((len(idxs) * self.lmb_page_bytes,
                             self._lmb_allocs[chunk].mmid))
         return [data[i] for i in range(len(slots))]
@@ -449,7 +452,7 @@ class LinkedBuffer:
         for chunk, idxs in self._runs_by_chunk(slots).items():
             offs = [slots[i] % self._lmb_chunk_pages for i in idxs]
             sub = (rows[np.asarray(idxs)] if hasattr(rows, "ndim")
-                   else jnp.stack([rows[i] for i in idxs]))
+                   else stack_pages([rows[i] for i in idxs]))
             self._lmb_write_run(chunk, offs, sub)
             charges.append((len(idxs) * self.lmb_page_bytes,
                             self._lmb_allocs[chunk].mmid))
@@ -735,7 +738,7 @@ class LinkedBuffer:
                     else freed.pop(0) for _ in faulting]
         # 4. one coalesced onboard scatter (zeros for first-touch pages)
         zero = jnp.zeros(self.page_shape, self.dtype)
-        batch = jnp.stack([data.get(p, zero) for p in faulting])
+        batch = stack_pages([data.get(p, zero) for p in faulting])
         self._onboard_pool = self.executor.write_pages(
             self._onboard_pool, assigned, batch)
         for p, slot in zip(faulting, assigned):
@@ -920,7 +923,7 @@ class LinkedBuffer:
                                  len(cands) * self.lmb_page_bytes)
         assigned = [self._onboard_free.pop() for _ in cands]
         self._onboard_pool = self.executor.write_pages(
-            self._onboard_pool, assigned, jnp.stack(data))
+            self._onboard_pool, assigned, stack_pages(data))
         for p, slot in zip(cands, assigned):
             entry = self._pages[p]
             self._lmb_slot_free(entry.slot)
@@ -991,9 +994,9 @@ class LinkedBuffer:
             slotmap = self._fault_in_many(occ)
             arr = self.executor.read_pages(
                 self._onboard_pool, [slotmap[p] for p in wave])
-            for j, p in enumerate(wave):
-                datas[p] = arr[j]
-        return jnp.stack([datas[p] for p in pages])
+            for p, row in zip(wave, arr):
+                datas[p] = row
+        return stack_pages([datas[p] for p in pages])
 
     def write_many(self, pages: Sequence[int], data) -> None:
         """Batched :meth:`write`: ``data[i]`` -> ``pages[i]`` with one

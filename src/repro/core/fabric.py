@@ -222,10 +222,11 @@ class FabricManager:
         """An expander's link bandwidth: its topology port when racked,
         else the uniform fabric default (also spares promoted from
         outside the topology)."""
+        from repro.rack.topology import TopologyError
         if self.topology is not None:
             try:
                 return self.topology.port_bandwidth_Bps(expander_id)
-            except Exception:
+            except TopologyError:
                 pass
         return self._link_bandwidth_Bps
 
@@ -246,11 +247,12 @@ class FabricManager:
     def domain_of(self, expander_id: int) -> Optional[str]:
         """The expander's correlated failure domain, None when no
         topology is configured (direct attach has no shared domains)."""
+        from repro.rack.topology import TopologyError
         if self.topology is None:
             return None
         try:
             return self.topology.domain_of(expander_id)
-        except Exception:
+        except TopologyError:
             return None
 
     @property

@@ -11,21 +11,17 @@ exposes it via sharding ``memory_kind``:
   * ``unpinned_host``— pageable host memory (needs a staging copy = the
                        paper's host-forwarded PCIe path)
 
-Two execution modes, auto-detected:
-
-  * **in-jit** (TPU): steps are compiled with ``memory_kind`` annotations on
-    offloaded operands/results so XLA schedules the HBM↔host DMAs and can
-    overlap them with compute.
-  * **host-stage** (CPU backend — used by tests/CI): the CPU runtime has no
-    ``annotate_device_placement`` custom-call, so tier residency is realized
-    with eager ``jax.device_put`` between compiled steps.  Functionally
-    identical, same accounting, no overlap.
+LMB page moves (:class:`TierExecutor`) are eager ``jax.device_put`` calls
+between compiled steps, the same code on every backend: the named pages
+cross, nothing else.  Whole-tree moves (:func:`put_tier`) serve training
+state; :func:`supports_in_jit_offload` says whether a backend can instead
+compile ``memory_kind`` annotations into a step.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Optional, Sequence
+from typing import Any, Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -38,14 +34,14 @@ DEVICE = "device"
 PINNED_HOST = "pinned_host"
 UNPINNED_HOST = "unpinned_host"
 
+#: ``jnp.stack`` compiled once per (page count, page shape): stacking a
+#: burst of pages eagerly dispatches one op per page
+stack_pages = jax.jit(jnp.stack)
+
 
 @functools.cache
 def backend_memory_kinds() -> tuple:
-    dev = jax.devices()[0]
-    try:
-        return tuple(m.kind for m in dev.addressable_memories())
-    except Exception:
-        return (DEVICE,)
+    return tuple(m.kind for m in jax.devices()[0].addressable_memories())
 
 
 @functools.cache
@@ -75,29 +71,23 @@ def with_memory_kind(sharding, memory_kind: str):
 
 
 def _aval_on_host(x: jax.Array) -> bool:
-    """True if the array's *aval* carries Host memory space.  JAX 0.8 CPU
-    quirk: slices of pinned_host arrays keep a sticky <host> aval even
-    through device_put(memory_kind='device'), and mixed-space operands are
-    rejected by ops like dynamic_update_slice — detect via the aval, not
-    the (sometimes lying) sharding.memory_kind."""
+    """True if the array's *aval* carries Host memory space.  The aval is
+    authoritative: an eager slice of a host array reports a ``device``
+    sharding while its aval (and data) stay in host memory, and ops that
+    mix it with device operands are rejected."""
     ms = getattr(x.aval, "memory_space", None)
     return ms is not None and "host" in str(ms).lower()
 
 
 def put_tier(x: jax.Array, memory_kind: str) -> jax.Array:
-    """Eagerly move an array to a tier (host-stage mode data path)."""
-    on_host = _aval_on_host(x)
-    if memory_kind == DEVICE:
-        if not on_host and getattr(x.sharding, "memory_kind",
-                                   DEVICE) in (None, DEVICE):
-            return x
-        # host->device via a host copy: the only path that clears the
-        # sticky Host aval on the CPU backend (a real DMA on TPU would be
-        # the in-jit path instead — see module docstring)
-        return jnp.asarray(np.asarray(x))
-    if on_host and getattr(x.sharding, "memory_kind", None) == memory_kind:
+    """Eagerly move an array to a tier (whole-tree moves of training
+    state)."""
+    if tier_of(x) == memory_kind:
         return x
-    return jax.device_put(x, with_memory_kind(x.sharding, memory_kind))
+    # may_alias=False: a host slice's sharding already names the device,
+    # and only a forced copy lands it in device memory
+    return jax.device_put(x, with_memory_kind(x.sharding, memory_kind),
+                          may_alias=False)
 
 
 def tree_put_tier(tree: Any, memory_kind: str) -> Any:
@@ -123,73 +113,97 @@ def nbytes_of(tree: Any) -> int:
                for l in leaves)
 
 
+class HostPool:
+    """An LMB-tier pool: one array per page, each in pinned host memory.
+
+    JAX gathers and scatters only within one memory space, so a single
+    host array cannot be indexed with device-memory indices.  Instead
+    every page is an array of its own, and a move hands exactly the named
+    pages to one ``jax.device_put``: its cost scales with the page count,
+    never with the pool.  A fresh pool shares one zero page across its
+    slots (arrays are immutable; a write replaces the slot's entry)."""
+
+    def __init__(self, pages: List[jax.Array]):
+        self.pages = pages
+
+    @property
+    def page_bytes(self) -> int:
+        return self.pages[0].nbytes
+
+
 class TierExecutor:
     """Executes LinkedBuffer page moves on JAX arrays.
 
-    Pages live in a pool array per tier; moves are slice copies.  In
-    host-stage mode the LMB-tier pool is a pinned-host array (real host
-    residency); if the backend has no host memories at all, the LMB tier is
-    a plain device array and only the accounting distinguishes tiers (pure
-    modeling mode — still exercises every allocator/policy path).
+    The onboard pool is one device (HBM) array, read and written with a
+    gather or scatter.  The LMB pool is a :class:`HostPool` in
+    ``pinned_host`` memory; its pages cross the host link with
+    ``jax.device_put``, on every backend alike.  A backend without
+    ``pinned_host`` memory has no LMB tier, and construction fails.
     """
 
-    def __init__(self, lmb_memory_kind: Optional[str] = None,
-                 meter: Optional[Callable[[int], float]] = None,
+    lmb_memory_kind = PINNED_HOST
+
+    def __init__(self, meter: Optional[Callable[[int], float]] = None,
                  trace: Optional[SpanTracer] = None):
-        kinds = backend_memory_kinds()
-        if lmb_memory_kind is None:
-            lmb_memory_kind = PINNED_HOST if PINNED_HOST in kinds else DEVICE
-        self.lmb_memory_kind = lmb_memory_kind
-        self.real_host_tier = lmb_memory_kind != DEVICE
+        if PINNED_HOST not in backend_memory_kinds():
+            raise RuntimeError(
+                f"backend {jax.default_backend()!r} has no {PINNED_HOST!r} "
+                f"memory (it has {backend_memory_kinds()}): the LMB tier "
+                "needs host memory the device can DMA")
+        dev = jax.devices()[0]
+        self._host = SingleDeviceSharding(dev, memory_kind=PINNED_HOST)
+        self._device = SingleDeviceSharding(dev, memory_kind=DEVICE)
         #: span tracer for coalesced pool transfers (wall-clock spans —
         #: the executor runs real JAX ops, unlike the modeled link path)
         self.trace = trace if trace is not None else GLOBAL_TRACER
         #: QoS hook: charged with nbytes for every page crossing the
         #: host<->device boundary (the expander-link analogue on a TPU
         #: host); typically LMBHost.meter_transfer bound to a device id.
-        #: In pure modeling mode (no host memories) executor-level moves
-        #: are indistinguishable from device ops, so consumers that still
-        #: want link accounting meter at their own layer (LinkedBuffer).
         self.meter = meter
 
-    def _meter(self, pool: jax.Array, nbytes: int) -> None:
-        if self.meter is not None and tier_of(pool) != DEVICE:
+    def _meter(self, pool, nbytes: int) -> None:
+        if self.meter is not None and isinstance(pool, HostPool):
             self.meter(nbytes)
 
     @staticmethod
-    def _page_bytes(pool: jax.Array) -> int:
+    def _page_bytes(pool) -> int:
+        if isinstance(pool, HostPool):
+            return pool.page_bytes
         return int(np.prod(pool.shape[1:])) * jnp.dtype(pool.dtype).itemsize
 
-    def alloc_pool(self, npages: int, page_shape: tuple, dtype,
-                   tier: str) -> jax.Array:
-        shape = (npages, *page_shape)
-        x = jnp.zeros(shape, dtype=dtype)
+    def alloc_pool(self, npages: int, page_shape: tuple, dtype, tier: str):
         if tier == "lmb":
-            x = put_tier(x, self.lmb_memory_kind)
-        return x
+            zero = jax.device_put(jnp.zeros(page_shape, dtype), self._host)
+            return HostPool([zero] * npages)
+        return jnp.zeros((npages, *page_shape), dtype=dtype)
 
-    def read_page(self, pool: jax.Array, slot: int) -> jax.Array:
+    def read_page(self, pool, slot: int) -> jax.Array:
         self._meter(pool, self._page_bytes(pool))
-        page = pool[slot]
-        return put_tier(page, DEVICE)
+        return self._read_page(pool, int(slot))
 
-    def write_page(self, pool: jax.Array, slot: int,
-                   page: jax.Array) -> jax.Array:
-        tier = tier_of(pool)
+    def _read_page(self, pool, slot: int) -> jax.Array:
+        if isinstance(pool, HostPool):
+            return jax.device_put(pool.pages[slot], self._device)
+        return pool[slot]
+
+    def write_page(self, pool, slot: int, page: jax.Array):
         self._meter(pool, self._page_bytes(pool))
-        page = put_tier(page, tier)
-        new = pool.at[slot].set(page)
-        return put_tier(new, tier)  # .at[].set may drop the memory kind
+        return self._write_page(pool, int(slot), jnp.asarray(page))
+
+    def _write_page(self, pool, slot: int, page: jax.Array):
+        if isinstance(pool, HostPool):
+            pool.pages[slot] = jax.device_put(page, self._host)
+            return pool
+        return pool.at[slot].set(page)
 
     # ---- coalesced multi-page transfers (the batched data path) ----
-    # One gather/scatter against the pool instead of N slice copies: on
-    # TPU this is one DMA descriptor per run, and the meter hook (when
-    # bound) sees ONE charge for the burst's total bytes — the overlap
-    # scheduler then has whole runs, not single pages, to hide behind
-    # compute.
+    # One gather/scatter against an onboard pool, or one device_put of
+    # the named pages of an LMB pool, instead of N single-page moves; the
+    # meter hook (when bound) sees ONE charge for the burst's total bytes
+    # — the overlap scheduler then has whole runs, not single pages, to
+    # hide behind compute.
 
-    def read_pages(self, pool: jax.Array,
-                   slots: Sequence[int]) -> jax.Array:
+    def read_pages(self, pool, slots: Sequence[int]) -> jax.Array:
         """Coalesced read: ``[len(slots), *page_shape]`` stacked onboard.
         Duplicate slots are allowed (a gather may repeat pages)."""
         self._meter(pool, self._page_bytes(pool) * len(slots))
@@ -197,44 +211,43 @@ class TierExecutor:
         if tr.enabled:
             with tr.span("exec.read_pages", op="demand",
                          nbytes=self._page_bytes(pool) * len(slots),
-                         pages=len(slots), tier=tier_of(pool)):
+                         pages=len(slots), tier=self._tier(pool)):
                 return self._read_pages(pool, slots)
         return self._read_pages(pool, slots)
 
-    def _read_pages(self, pool: jax.Array,
-                    slots: Sequence[int]) -> jax.Array:
+    def _read_pages(self, pool, slots: Sequence[int]) -> jax.Array:
         if len(slots) == 1:
-            # basic indexing beats a 1-element gather by ~10x in eager
-            # dispatch — the decode path (1 page per step) lives here
-            return put_tier(pool[int(slots[0])], DEVICE)[None]
-        batch = pool[jnp.asarray(np.asarray(slots, np.int32))]
-        return put_tier(batch, DEVICE)
+            # a one-page burst skips the gather/stack machinery (~10x in
+            # eager dispatch) — the decode path (1 page per step) lives here
+            return self._read_page(pool, int(slots[0]))[None]
+        if isinstance(pool, HostPool):
+            return stack_pages(jax.device_put(
+                [pool.pages[int(s)] for s in slots], self._device))
+        return pool[jnp.asarray(np.asarray(slots, np.int32))]
 
-    def write_pages(self, pool: jax.Array, slots: Sequence[int],
-                    pages: jax.Array) -> jax.Array:
+    def write_pages(self, pool, slots: Sequence[int], pages: jax.Array):
         """Coalesced write of ``pages[i] -> pool[slots[i]]``.  Slots must
         be distinct (scatter order over duplicates is undefined)."""
-        tier = tier_of(pool)
         self._meter(pool, self._page_bytes(pool) * len(slots))
         tr = self.trace
         if tr.enabled:
             with tr.span("exec.write_pages", op="demand",
                          nbytes=self._page_bytes(pool) * len(slots),
-                         pages=len(slots), tier=tier):
-                return self._write_pages(pool, slots, pages, tier)
-        return self._write_pages(pool, slots, pages, tier)
+                         pages=len(slots), tier=self._tier(pool)):
+                return self._write_pages(pool, slots, pages)
+        return self._write_pages(pool, slots, pages)
 
-    def _write_pages(self, pool: jax.Array, slots: Sequence[int],
-                     pages: jax.Array, tier: str) -> jax.Array:
-        pages = put_tier(jnp.asarray(pages), tier)
+    def _write_pages(self, pool, slots: Sequence[int], pages: jax.Array):
+        pages = jnp.asarray(pages)
         if len(slots) == 1:
-            new = pool.at[int(slots[0])].set(pages[0])
-        else:
-            idx = jnp.asarray(np.asarray(slots, np.int32))
-            new = pool.at[idx].set(pages)
-        return put_tier(new, tier)  # .at[].set may drop the memory kind
+            return self._write_page(pool, int(slots[0]), pages[0])
+        if isinstance(pool, HostPool):
+            rows = jax.device_put(list(pages), self._host)
+            for s, row in zip(slots, rows):
+                pool.pages[int(s)] = row
+            return pool
+        return pool.at[jnp.asarray(np.asarray(slots, np.int32))].set(pages)
 
-    def move_page(self, src_pool: jax.Array, src_slot: int,
-                  dst_pool: jax.Array, dst_slot: int) -> jax.Array:
-        return self.write_page(dst_pool, dst_slot,
-                               self.read_page(src_pool, src_slot))
+    @staticmethod
+    def _tier(pool) -> str:
+        return PINNED_HOST if isinstance(pool, HostPool) else DEVICE

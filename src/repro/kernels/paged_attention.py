@@ -8,13 +8,17 @@ rides in SMEM via scalar prefetch — the Pallas equivalent of "allocator
 metadata stays host-side / on-board" (§3.2): the lookup never touches the
 paged data tier.
 
-Grid (B, KV): the page walk happens INSIDE the kernel as a fori_loop over
+Grid (B,): the page walk happens INSIDE the kernel as a fori_loop over
 the sequence's live pages, with **double-buffered K/V page loads** — while
 page i feeds the softmax/matmul, page i+1's DMA from the HBM pool is
-already in flight (the PR 5 link-layer overlap idea pushed down into the
-kernel; see the double-buffering pattern in the Pallas guide).  The pool
-arrays stay in ``TPUMemorySpace.ANY`` (HBM) and only the two in-flight
-pages ever occupy VMEM, so pool size is bounded by HBM, not VMEM.
+already in flight (the link-layer overlap idea pushed down into the
+kernel).  Each DMA moves one whole page, all KV heads at once: the KV
+axis is tiled in HBM, so a per-head slice of a page would cut the tile,
+which the TPU compiler refuses.  A static loop over the KV heads then
+reads each head's
+``[T, hd]`` slab from VMEM.  The pool arrays stay in ``pl.ANY`` (HBM) and
+only the two in-flight pages ever occupy VMEM, so pool size is bounded by
+HBM, not VMEM.
 
 Unmapped pages (table entry -1) are clamped to page 0 for the DMA and
 masked out of the softmax — reads are always in-bounds (IOMMU discipline)
@@ -41,9 +45,8 @@ NEG_INF = -1e30
 
 def _pa_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                k_buf, v_buf, sem, m_ref, l_ref, acc_ref,
-               *, page_tokens: int):
+               *, page_tokens: int, kv_heads: int):
     b = pl.program_id(0)
-    h = pl.program_id(1)
     T = page_tokens
     length = len_ref[b]
     n_pages = (length + T - 1) // T
@@ -53,19 +56,19 @@ def _pa_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def page_dma(slot, ip):
-        """Async copies pool page table[b, ip] (head h) into VMEM slot."""
+        """Async copies pool page table[b, ip] (all KV heads) into VMEM
+        slot: one whole-page DMA each for K and V, so no copy cuts the
+        tiled KV axis."""
         page = jnp.maximum(table_ref[b, ip], 0)
-        return (pltpu.make_async_copy(k_hbm.at[page, :, h],
-                                      k_buf.at[slot], sem.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[page, :, h],
-                                      v_buf.at[slot], sem.at[slot, 1]))
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot],
+                                      sem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot],
+                                      sem.at[slot, 1]))
 
     @pl.when(n_pages > 0)
     def _warmup():
         for cp in page_dma(0, 0):
             cp.start()
-
-    q = q_ref[...].astype(jnp.float32)              # [G, hd]
 
     def body(ip, _):
         slot = jax.lax.rem(ip, 2)
@@ -78,23 +81,26 @@ def _pa_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         for cp in page_dma(slot, ip):
             cp.wait()
-        k = k_buf[slot].astype(jnp.float32)         # [T, hd]
-        v = v_buf[slot].astype(jnp.float32)         # [T, hd]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())))         # [G, T]
-        pos = ip * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = (pos < length) & (table_ref[b, ip] >= 0)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        # masked lanes contribute exactly zero even when the whole page
-        # is masked (m stays at NEG_INF, so exp(s - m) would be 1, not 0)
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
+        for h in range(kv_heads):
+            q = q_ref[h].astype(jnp.float32)            # [G, hd]
+            k = k_buf[slot, :, h, :].astype(jnp.float32)  # [T, hd]
+            v = v_buf[slot, :, h, :].astype(jnp.float32)  # [T, hd]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())))         # [G, T]
+            pos = ip * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            valid = (pos < length) & (table_ref[b, ip] >= 0)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            # masked lanes contribute exactly zero even when the whole
+            # page is masked (m stays at NEG_INF, so exp(s - m) would be
+            # 1, not 0)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, -1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())))
+            m_ref[h] = m_new
         return 0
 
     jax.lax.fori_loop(0, n_pages, body, 0)
@@ -118,27 +124,26 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((None, None, G, hd),
-                         lambda b, h, tbl, ln: (b, h, 0, 0)),
+            pl.BlockSpec((None, KV, G, hd), lambda b, tbl, ln: (b, 0, 0, 0)),
             # the pool stays in HBM; the kernel DMAs pages on demand
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((None, None, G, hd),
-                               lambda b, h, tbl, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((None, KV, G, hd),
+                               lambda b, tbl, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, T, hd), k_pages.dtype),   # double buffer: K
-            pltpu.VMEM((2, T, hd), v_pages.dtype),   # double buffer: V
-            pltpu.SemaphoreType.DMA((2, 2)),         # [slot, k/v]
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((2, T, KV, hd), k_pages.dtype),  # double buffer: K
+            pltpu.VMEM((2, T, KV, hd), v_pages.dtype),  # double buffer: V
+            pltpu.SemaphoreType.DMA((2, 2)),            # [slot, k/v]
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_pa_kernel, page_tokens=T),
+        functools.partial(_pa_kernel, page_tokens=T, kv_heads=KV),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,

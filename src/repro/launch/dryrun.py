@@ -45,13 +45,8 @@ from repro.train.loop import abstract_train_state, make_train_step
 
 
 def _cost_dict(compiled) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions: older
-    releases return ``[{...}]`` (one dict per device program), newer ones
-    the dict itself, and some backends ``None``."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()``, or ``{}`` where a backend has none."""
+    return compiled.cost_analysis() or {}
 
 
 def opt_state_shardings(opt_shapes, mesh, cfg, fsdp=False):

@@ -2,6 +2,9 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
         --requests 16 --decode-slots 4
+
+serves the published config (random weights); ``--reduced`` swaps in
+the tiny same-family config for a CPU run.
 """
 
 from __future__ import annotations
@@ -15,6 +18,7 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.core import DeviceSpec, HostSpec, LMBSystem, SystemSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.models.flags import Flags
 from repro.serve import EngineConfig, ServeEngine, SubmitSpec
@@ -28,11 +32,17 @@ def main() -> None:
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--onboard-pages", type=int, default=16)
     ap.add_argument("--pool-gib", type=int, default=4)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config instead of the "
+                         "published widths")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
+    use_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     model = build_model(cfg, Flags(remat=False))
-    params = model.init(jax.random.key(0))
+    params = jax.jit(model.init)(jax.random.key(0))
 
     spec = SystemSpec(expanders=1, pool_gib=args.pool_gib,
                       hosts=(HostSpec("server", page_bytes=4096),),

@@ -28,7 +28,7 @@ import numpy as np
 from repro.core.api import LMBHost
 from repro.core.buffer import LinkedBuffer
 from repro.core.client import LMBSystem
-from repro.core.offload import TierExecutor
+from repro.core.offload import TierExecutor, stack_pages
 from repro.core.overlap import OverlapScheduler
 
 
@@ -163,7 +163,7 @@ class PagedKVStore:
             jax.lax.dynamic_update_slice_in_dim(
                 cur[i], kv[:, :, done:done + take], off, axis=2)
             for i, (page, off, take, done) in enumerate(segs)]
-        self.buf.write_many(pages, jnp.stack(updated))
+        self.buf.write_many(pages, stack_pages(updated))
         seq.length = length
 
     def gather_seq(self, sid: int) -> jax.Array:

@@ -1,10 +1,10 @@
 """Activation-constraint helper + metrics accounting."""
 
-import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core.metrics import Metrics
+from repro.launch.mesh import make_host_mesh
 from repro.sharding.constraints import activation_mesh, constrain
 
 
@@ -15,7 +15,7 @@ class TestConstraints:
         assert y is x
 
     def test_applies_inside_context(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh()
         x = jnp.ones((4, 8, 16))
         with activation_mesh(mesh):
             y = constrain(x, "residual")
@@ -24,14 +24,14 @@ class TestConstraints:
         assert y.shape == x.shape and z.shape == x.shape
 
     def test_divisibility_degrades_not_crashes(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh()
         with activation_mesh(mesh):
             # odd dims that divide nothing still pass through
             out = constrain(jnp.ones((3, 5, 7)), "residual")
         assert out.shape == (3, 5, 7)
 
     def test_decode_single_token_residual(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh()
         with activation_mesh(mesh):
             out = constrain(jnp.ones((2, 1, 16)), "residual")
         assert out.shape == (2, 1, 16)

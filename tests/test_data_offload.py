@@ -5,8 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.offload import (DEVICE, PINNED_HOST, backend_memory_kinds,
-                                put_tier, tier_of, tree_put_tier, nbytes_of)
+from repro.core.offload import (DEVICE, PINNED_HOST, HostPool, TierExecutor,
+                                backend_memory_kinds, put_tier, tier_of,
+                                tree_put_tier, nbytes_of)
 from repro.data.pipeline import DataConfig, SyntheticLM, make_dataset
 
 
@@ -59,8 +60,8 @@ class TestTiers:
         np.testing.assert_array_equal(np.asarray(d), np.asarray(x))
 
     def test_host_slice_cleared_to_device(self):
-        """Slices of host arrays must come back fully device-spaced (the
-        JAX 0.8 sticky-<host>-aval quirk regression test)."""
+        """Slices of host arrays must come back fully device-spaced (an
+        eager slice keeps a host aval under a device sharding)."""
         if PINNED_HOST not in backend_memory_kinds():
             pytest.skip("no host memory kinds")
         pool = put_tier(jnp.zeros((4, 2, 2)), PINNED_HOST)
@@ -77,3 +78,30 @@ class TestTiers:
             ht = tree_put_tier(tree, PINNED_HOST)
             assert all(tier_of(l) == PINNED_HOST
                        for l in jax.tree_util.tree_leaves(ht))
+
+    def test_host_pool_moves_only_named_pages(self):
+        """Multi-page writes then reads through a pinned_host LMB pool:
+        the pool stays in host memory, the contents round-trip, and only
+        the named pages move (metered bytes and untouched slots)."""
+        moved = []
+        ex = TierExecutor(meter=moved.append)
+        pool = ex.alloc_pool(8, (4, 3), jnp.bfloat16, tier="lmb")
+        assert isinstance(pool, HostPool)
+        page_bytes = 4 * 3 * 2
+        before = list(pool.pages)
+        data = jnp.arange(3 * 12, dtype=jnp.bfloat16).reshape(3, 4, 3)
+        pool = ex.write_pages(pool, [5, 1, 6], data)
+        assert all(tier_of(p) == PINNED_HOST for p in pool.pages)
+        untouched = [s for s in range(8) if s not in (5, 1, 6)]
+        assert all(pool.pages[s] is before[s] for s in untouched)
+        got = ex.read_pages(pool, [6, 5])
+        assert tier_of(got) == DEVICE
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(data)[[2, 0]])
+        np.testing.assert_array_equal(np.asarray(ex.read_page(pool, 1)),
+                                      np.asarray(data[1]))
+        assert moved == [3 * page_bytes, 2 * page_bytes, page_bytes]
+        # onboard (device) pools are not link traffic
+        onboard = ex.alloc_pool(4, (4, 3), jnp.bfloat16, tier="onboard")
+        onboard = ex.write_pages(onboard, [0, 2], data[:2])
+        assert tier_of(onboard) == DEVICE and len(moved) == 3

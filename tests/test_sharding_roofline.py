@@ -96,5 +96,6 @@ def test_dryrun_single_cell_subprocess():
         "print('CELL-OK')\n")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=1200,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                              "JAX_PLATFORMS": "cpu"})
     assert "CELL-OK" in out.stdout, out.stderr[-2000:]
