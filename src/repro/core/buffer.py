@@ -31,6 +31,12 @@ Capabilities:
     beyond-paper: cold pages cost 1/4 the pool bytes and PCIe traffic
     (per-page absmax scale kept in HOST metadata, like all LMB metadata);
     lossy (~1e-2 relative) — suited to KV caches, not optimizer state
+  * **spans at the tier's boundary** — with the FM's tracer on, every
+    public data-path call (:meth:`read`, :meth:`write`, :meth:`read_many`,
+    :meth:`write_many`, :meth:`append_pages`, :meth:`release`,
+    :meth:`schedule_prefetch`, :meth:`note_compute_window`) runs under an
+    ``lmb.<call>`` span; ``fault``/``fault.batch``, ``evict.batch``,
+    ``prefetch.burst`` and the executor's ``exec.*`` spans nest inside
   * **per-page access heat** (exponentially-decayed touch counters fed by
     the link-metering path, numpy-backed so batch updates are one
     vectorized decay instead of a dict walk; decayed-cold entries are
@@ -54,6 +60,7 @@ CostAwareLRU's clean-page preference routinely did (self-thrash).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -72,6 +79,21 @@ from repro.obs.trace import SpanTracer
 
 ONBOARD = "onboard"
 LMB = "lmb"
+
+
+def _lmb_span(fn):
+    """Run a public LinkedBuffer call under an ``lmb.<name>`` span when
+    the FM's tracer is on."""
+    name = "lmb." + fn.__name__
+
+    @functools.wraps(fn)
+    def traced(self, *args, **kwargs):
+        tr = self.host.fm.tracer
+        if not tr.enabled:
+            return fn(self, *args, **kwargs)
+        with tr.span(name):
+            return fn(self, *args, **kwargs)
+    return traced
 
 
 @dataclasses.dataclass
@@ -104,7 +126,7 @@ class LinkedBuffer:
         self.name = name
         self.device_id = device_id
         self.host = host
-        self.executor = executor or TierExecutor()
+        self.executor = executor or TierExecutor(trace=host.fm.tracer)
         self.page_shape = tuple(page_shape)
         self.dtype = dtype
         self.onboard_pages = int(onboard_pages)
@@ -230,6 +252,7 @@ class LinkedBuffer:
                 if pool is not None for p in pool.pages}
 
     # --------------------------------------------------------------- allocation
+    @_lmb_span
     def append_pages(self, n: int = 1) -> List[int]:
         """Extend the logical buffer by ``n`` zero pages; returns indices."""
         base = len(self._pages)
@@ -438,6 +461,7 @@ class LinkedBuffer:
         for chunk, idxs in self._runs_by_chunk(slots).items():
             offs = [slots[i] % self._lmb_chunk_pages for i in idxs]
             arr = self._lmb_read_run(chunk, offs)
+            self.executor.count_hbm(arr.nbytes)        # the rows
             for i, row in zip(idxs, arr):
                 data[i] = row
             charges.append((len(idxs) * self.lmb_page_bytes,
@@ -453,6 +477,7 @@ class LinkedBuffer:
             offs = [slots[i] % self._lmb_chunk_pages for i in idxs]
             sub = (rows[np.asarray(idxs)] if hasattr(rows, "ndim")
                    else stack_pages([rows[i] for i in idxs]))
+            self.executor.count_hbm(sub.nbytes)
             self._lmb_write_run(chunk, offs, sub)
             charges.append((len(idxs) * self.lmb_page_bytes,
                             self._lmb_allocs[chunk].mmid))
@@ -520,8 +545,12 @@ class LinkedBuffer:
         defer the metering flush to one combined burst."""
         if k <= 0:
             return []
-        tr = self.trace
-        t0 = tr.now() if tr.enabled else 0.0
+        with self.trace.span("evict.batch", op="demand",
+                             nbytes=k * self.lmb_page_bytes, pages=k):
+            return self._evict_victims(k, sink)
+
+    def _evict_victims(self, k: int,
+                       sink: Optional[Tuple[list, list]]) -> List[int]:
         victims = self.policy.victims(k)
         if len(victims) < k:
             raise OutOfMemory(
@@ -552,9 +581,6 @@ class LinkedBuffer:
             freed.append(slot)
         if sink is None:
             self._charge_links(charges, heat)
-        if tr.enabled:
-            tr.add("evict.batch", t0, tr.now() - t0, op="demand",
-                   nbytes=k * self.lmb_page_bytes, pages=k)
         return freed
 
     def _onboard_slot_alloc(self) -> int:
@@ -577,8 +603,15 @@ class LinkedBuffer:
                 self._prefetch_runs()
             return entry.slot
         self.metrics.record_miss(self.name, ONBOARD, self.page_bytes)
-        tr = self.trace
-        t0 = tr.now() if tr.enabled else 0.0
+        with self.trace.span("fault", op="demand", nbytes=self.page_bytes,
+                             page=page):
+            slot = self._fault_miss(page, entry)
+        if self.prefetcher:
+            self.prefetcher.observe(page)
+            self._prefetch_runs()
+        return slot
+
+    def _fault_miss(self, page: int, entry: PageEntry) -> int:
         slot = self._onboard_slot_alloc()
         if entry.tier == LMB:
             data = self._lmb_read(entry.slot, page)
@@ -590,18 +623,13 @@ class LinkedBuffer:
             self._lmb_owner.pop(entry.slot, None)
         else:
             # first touch: zero-fill
+            self.executor.count_hbm(self.page_bytes)
             self._onboard_pool = self.executor.write_page(
                 self._onboard_pool, slot,
                 jnp.zeros(self.page_shape, self.dtype))
         entry.tier, entry.slot, entry.dirty = ONBOARD, slot, False
         self._onboard_owner[slot] = page
         self.policy.on_insert(page)
-        if tr.enabled:
-            tr.add("fault", t0, tr.now() - t0, op="demand",
-                   nbytes=self.page_bytes, page=page)
-        if self.prefetcher:
-            self.prefetcher.observe(page)
-            self._prefetch_runs()
         return slot
 
     # --------------------------------------------------------- batched paging
@@ -739,6 +767,7 @@ class LinkedBuffer:
         # 4. one coalesced onboard scatter (zeros for first-touch pages)
         zero = jnp.zeros(self.page_shape, self.dtype)
         batch = stack_pages([data.get(p, zero) for p in faulting])
+        self.executor.count_hbm(zero.nbytes + batch.nbytes)
         self._onboard_pool = self.executor.write_pages(
             self._onboard_pool, assigned, batch)
         for p, slot in zip(faulting, assigned):
@@ -914,16 +943,22 @@ class LinkedBuffer:
         cands = cands[:len(self._onboard_free)]
         if not cands:
             return
-        tr = self.trace
-        t0 = tr.now() if tr.enabled else 0.0
+        with self.trace.span("prefetch.burst", op="prefetch",
+                             nbytes=len(cands) * self.lmb_page_bytes,
+                             pages=len(cands)):
+            self._prefetch_burst(cands)
+
+    def _prefetch_burst(self, cands: List[int]) -> None:
         charges: List[Tuple[int, Optional[int]]] = []
         src_slots = [self._pages[p].slot for p in cands]
         data = self._read_runs(src_slots, charges)
         self.metrics.record_move(self.name, LMB, ONBOARD,
                                  len(cands) * self.lmb_page_bytes)
         assigned = [self._onboard_free.pop() for _ in cands]
+        batch = stack_pages(data)
+        self.executor.count_hbm(batch.nbytes)
         self._onboard_pool = self.executor.write_pages(
-            self._onboard_pool, assigned, stack_pages(data))
+            self._onboard_pool, assigned, batch)
         for p, slot in zip(cands, assigned):
             entry = self._pages[p]
             self._lmb_slot_free(entry.slot)
@@ -935,17 +970,15 @@ class LinkedBuffer:
         self.prefetch_bursts += 1
         self.prefetch_pages_total += len(cands)
         self._charge_links(charges, cands, op="prefetch")
-        if tr.enabled:
-            tr.add("prefetch.burst", t0, tr.now() - t0, op="prefetch",
-                   nbytes=len(cands) * self.lmb_page_bytes,
-                   pages=len(cands))
 
     # ------------------------------------------------------------------- API
+    @_lmb_span
     def read(self, page: int) -> jax.Array:
         self._check(page)
         slot = self._fault_in(page)
         return self.executor.read_page(self._onboard_pool, slot)
 
+    @_lmb_span
     def write(self, page: int, data) -> None:
         self._check(page)
         entry = self._pages[page]
@@ -963,6 +996,7 @@ class LinkedBuffer:
         if hasattr(self.policy, "mark_dirty"):
             self.policy.mark_dirty(page, True)
 
+    @_lmb_span
     def read_many(self, pages: Sequence[int]) -> jax.Array:
         """Batched :meth:`read`: fault the pages in with coalesced
         per-chunk transfers and bulk eviction, then return them stacked
@@ -980,6 +1014,7 @@ class LinkedBuffer:
             # scalar dispatch cost
             data = self.read(order[0])
             self._record_dup_hits(order[0], len(pages) - 1)
+            self.executor.count_hbm(data.nbytes * len(pages))
             if len(pages) == 1:
                 return data[None]
             return jnp.stack([data] * len(pages))
@@ -996,8 +1031,11 @@ class LinkedBuffer:
                 self._onboard_pool, [slotmap[p] for p in wave])
             for p, row in zip(wave, arr):
                 datas[p] = row
+        # the waves' rows, then their stack
+        self.executor.count_hbm(self.page_bytes * (len(datas) + len(pages)))
         return stack_pages([datas[p] for p in pages])
 
+    @_lmb_span
     def write_many(self, pages: Sequence[int], data) -> None:
         """Batched :meth:`write`: ``data[i]`` -> ``pages[i]`` with one
         coalesced onboard scatter after a batched fault (duplicate pages:
@@ -1015,11 +1053,13 @@ class LinkedBuffer:
         order = list(dict.fromkeys(pages))
         last = {p: i for i, p in enumerate(pages)}
         if len(order) == 1:
+            self.executor.count_hbm(self.page_bytes)
             self.write(order[0], data[last[order[0]]])
             self._record_dup_hits(order[0], len(pages) - 1)
             return
         for wave, occ in self._iter_waves(pages, order):
             slotmap = self._fault_in_many(occ)
+            self.executor.count_hbm(self.page_bytes * len(wave))
             self._onboard_pool = self.executor.write_pages(
                 self._onboard_pool, [slotmap[p] for p in wave],
                 data[np.asarray([last[p] for p in wave])])
@@ -1057,6 +1097,7 @@ class LinkedBuffer:
         for p in dict.fromkeys(pages):
             self.policy.unpin(p)
 
+    @_lmb_span
     def schedule_prefetch(self, pages: Sequence[int]) -> None:
         """Feed exact future page knowledge (a scheduler's next-round
         access list) to the prefetcher and issue as much of it as fits
@@ -1074,6 +1115,7 @@ class LinkedBuffer:
             if self.prefetcher.pending() >= before:
                 break       # budgets exhausted (deferred) — later rounds
 
+    @_lmb_span
     def note_compute_window(self, seconds: float,
                             observed: bool = True) -> None:
         """Open a new overlap window sized to the consumer's compute
@@ -1106,6 +1148,7 @@ class LinkedBuffer:
             out.append(p)
         return out
 
+    @_lmb_span
     def release(self, page: int) -> None:
         """Refcount--; frees storage at zero."""
         self._check(page)

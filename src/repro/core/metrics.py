@@ -100,6 +100,10 @@ class Metrics:
     def inc(self, name: str, value: float = 1.0) -> None:
         self._counters[name] += value
 
+    def counter(self, name: str) -> float:
+        """A counter's value (0 before its first ``inc``)."""
+        return self._counters.get(name, 0.0)
+
     def gauge(self, name: str, value: float) -> None:
         self._gauges[name] = float(value)
 

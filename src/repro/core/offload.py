@@ -13,7 +13,9 @@ exposes it via sharding ``memory_kind``:
 
 LMB page moves (:class:`TierExecutor`) are eager ``jax.device_put`` calls
 between compiled steps, the same code on every backend: the named pages
-cross, nothing else.  Whole-tree moves (:func:`put_tier`) serve training
+cross, nothing else.  With its tracer on, the executor's moves run under
+``exec.read_page(s)`` / ``exec.write_page(s)`` spans; its ``hbm_meter``
+hook is charged the bytes each of its ops writes on HBM.  Whole-tree moves (:func:`put_tier`) serve training
 state; :func:`supports_in_jit_offload` says whether a backend can instead
 compile ``memory_kind`` annotations into a step.
 """
@@ -144,7 +146,8 @@ class TierExecutor:
     lmb_memory_kind = PINNED_HOST
 
     def __init__(self, meter: Optional[Callable[[int], float]] = None,
-                 trace: Optional[SpanTracer] = None):
+                 trace: Optional[SpanTracer] = None,
+                 hbm_meter: Optional[Callable[[int], None]] = None):
         if PINNED_HOST not in backend_memory_kinds():
             raise RuntimeError(
                 f"backend {jax.default_backend()!r} has no {PINNED_HOST!r} "
@@ -160,6 +163,14 @@ class TierExecutor:
         #: host<->device boundary (the expander-link analogue on a TPU
         #: host); typically LMBHost.meter_transfer bound to a device id.
         self.meter = meter
+        #: charged with the bytes every array op of the data path writes
+        #: on HBM: gathers, slices, stacks, and each onboard scatter the
+        #: whole pool (the update is not donated)
+        self.hbm_meter = hbm_meter
+
+    def count_hbm(self, nbytes: int) -> None:
+        if self.hbm_meter is not None:
+            self.hbm_meter(nbytes)
 
     def _meter(self, pool, nbytes: int) -> None:
         if self.meter is not None and isinstance(pool, HostPool):
@@ -179,21 +190,35 @@ class TierExecutor:
 
     def read_page(self, pool, slot: int) -> jax.Array:
         self._meter(pool, self._page_bytes(pool))
+        tr = self.trace
+        if tr.enabled:
+            with tr.span("exec.read_page", op="demand",
+                         nbytes=self._page_bytes(pool),
+                         tier=self._tier(pool)):
+                return self._read_page(pool, int(slot))
         return self._read_page(pool, int(slot))
 
     def _read_page(self, pool, slot: int) -> jax.Array:
         if isinstance(pool, HostPool):
             return jax.device_put(pool.pages[slot], self._device)
+        self.count_hbm(self._page_bytes(pool))
         return pool[slot]
 
     def write_page(self, pool, slot: int, page: jax.Array):
         self._meter(pool, self._page_bytes(pool))
+        tr = self.trace
+        if tr.enabled:
+            with tr.span("exec.write_page", op="demand",
+                         nbytes=self._page_bytes(pool),
+                         tier=self._tier(pool)):
+                return self._write_page(pool, int(slot), jnp.asarray(page))
         return self._write_page(pool, int(slot), jnp.asarray(page))
 
     def _write_page(self, pool, slot: int, page: jax.Array):
         if isinstance(pool, HostPool):
             pool.pages[slot] = jax.device_put(page, self._host)
             return pool
+        self.count_hbm(pool.nbytes)
         return pool.at[slot].set(page)
 
     # ---- coalesced multi-page transfers (the batched data path) ----
@@ -219,7 +244,9 @@ class TierExecutor:
         if len(slots) == 1:
             # a one-page burst skips the gather/stack machinery (~10x in
             # eager dispatch) — the decode path (1 page per step) lives here
+            self.count_hbm(self._page_bytes(pool))
             return self._read_page(pool, int(slots[0]))[None]
+        self.count_hbm(self._page_bytes(pool) * len(slots))
         if isinstance(pool, HostPool):
             return stack_pages(jax.device_put(
                 [pool.pages[int(s)] for s in slots], self._device))
@@ -240,12 +267,15 @@ class TierExecutor:
     def _write_pages(self, pool, slots: Sequence[int], pages: jax.Array):
         pages = jnp.asarray(pages)
         if len(slots) == 1:
+            self.count_hbm(pages.nbytes)             # the row
             return self._write_page(pool, int(slots[0]), pages[0])
         if isinstance(pool, HostPool):
+            self.count_hbm(pages.nbytes)             # the rows
             rows = jax.device_put(list(pages), self._host)
             for s, row in zip(slots, rows):
                 pool.pages[int(s)] = row
             return pool
+        self.count_hbm(pool.nbytes)
         return pool.at[jnp.asarray(np.asarray(slots, np.int32))].set(pages)
 
     @staticmethod

@@ -24,6 +24,14 @@ seconds for compute-side spans, **modeled virtual seconds** for link
 transfer spans (the arbiter's ``TransferGrant.delay_s``), which is what
 makes span sums reconcile exactly with the fabric byte/wait counters.
 Exporters record which convention a span used via its name/args.
+Every wall-clock span opened with :meth:`SpanTracer.span` also enters a
+``jax.profiler.TraceAnnotation`` of its name, so under a JAX profiler
+session it lands on the profile's host plane, on the device trace's
+clock, beside the device ops it waited on.  Spans recorded after the
+fact with :meth:`SpanTracer.add` (``link.xfer``, whose duration is
+modeled) are never annotated.  :func:`profiling` says whether such a
+session is recording; the serve engine traces the rounds it runs under
+one (``ServeEngine.step``).
 """
 
 from __future__ import annotations
@@ -36,6 +44,22 @@ from typing import Any, Dict, Iterator, List, Optional
 
 #: shared cap for the span ring and for ``Metrics._events``
 DEFAULT_RING_CAPACITY = 65536
+
+#: ``jax.profiler.TraceAnnotation``, imported on first use so that this
+#: module imports nothing beyond the stdlib
+_Annotation = None
+
+
+def _annotation():
+    global _Annotation
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation as _Annotation
+    return _Annotation
+
+
+def profiling() -> bool:
+    """Whether a JAX profiler session is recording host annotations."""
+    return _annotation().is_enabled()
 
 
 @dataclass
@@ -130,30 +154,37 @@ class SpanTracer:
         return self.add(name, self.now(), 0.0, **kw)
 
     @contextmanager
-    def _span_cm(self, name: str, kw: Dict[str, Any]) -> Iterator[int]:
+    def _span_cm(self, name: str,
+                 kw: Dict[str, Any]) -> Iterator[Dict[str, Any]]:
         with self._lock:
             sid = self._next_id
             self._next_id += 1
             parent = self._stack[-1] if self._stack else None
             self._stack.append(sid)
-        t0 = self.now()
-        try:
-            yield sid
-        finally:
-            dur = self.now() - t0
-            with self._lock:
-                if self._stack and self._stack[-1] == sid:
-                    self._stack.pop()
-                elif sid in self._stack:    # unbalanced exit
-                    self._stack.remove(sid)
-            self.add(name, t0, dur, parent_id=parent, span_id=sid, **kw)
+        with _annotation()(name):
+            t0 = self.now()
+            try:
+                yield kw
+            finally:
+                dur = self.now() - t0
+                with self._lock:
+                    if self._stack and self._stack[-1] == sid:
+                        self._stack.pop()
+                    elif sid in self._stack:    # unbalanced exit
+                        self._stack.remove(sid)
+                self.add(name, t0, dur, parent_id=parent, span_id=sid,
+                         **kw)
 
     def span(self, name: str, **kw: Any):
-        """Context manager recording a wall-clock span around a block.
+        """Context manager recording a wall-clock span around a block,
+        inside a profiler annotation of the same name.
 
         Children recorded while the block is open (via nested ``span``
-        or plain ``add``/``event``) get this span as their parent.
-        When disabled, returns a shared no-op — no allocation.
+        or plain ``add``/``event``) get this span as their parent.  The
+        block receives the span's keyword arguments as a dict, which it
+        may add to before the span closes (args known only inside it).
+        When disabled, returns a shared no-op that yields ``None`` — no
+        allocation.
         """
         if not self.enabled:
             return _NULL_SPAN

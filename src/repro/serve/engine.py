@@ -34,7 +34,7 @@ from repro.core.api import LMBHost
 from repro.core.client import LMBSystem
 from repro.core.pool import OutOfMemory
 from repro.models.zoo import Model
-from repro.obs.trace import DEFAULT_RING_CAPACITY, SpanTracer
+from repro.obs.trace import SpanTracer, profiling
 from repro.qos.slo import AdmissionController, Decision
 from repro.serve.kv_cache import PagedKVStore
 
@@ -140,10 +140,10 @@ class EngineConfig:
     #: record spans (serve rounds, TTFT/token events, the KV data path)
     #: into a private tracer attached to the engine's fabric — unless
     #: the fabric already carries an enabled tracer (LMBSystem with
-    #: ObsSpec.trace, or benchmarks' global tracer), which is reused
+    #: ObsSpec.trace, or benchmarks' global tracer), which is reused.
+    #: With it off, rounds that run under a JAX profiler session are
+    #: traced all the same (see :meth:`ServeEngine.step`)
     trace: bool = False
-    #: ring capacity of the engine-minted tracer
-    trace_capacity: int = DEFAULT_RING_CAPACITY
 
 
 class ServeEngine:
@@ -177,7 +177,7 @@ class ServeEngine:
         # data path records into the same ring as the serve rounds
         self.trace: SpanTracer = host.fm.tracer
         if ecfg.trace and not self.trace.enabled:
-            self.trace = SpanTracer(capacity=ecfg.trace_capacity)
+            self.trace = SpanTracer()
             host.fm.tracer = self.trace
         overlap = None
         if ecfg.kv_prefetch and ecfg.kv_prefetch_depth:
@@ -267,14 +267,15 @@ class ServeEngine:
         # everything back from the pool, so holding the dense cache per
         # request would defeat the capacity story
         req._cache = None if self._use_paged else cache
-        nxt = int(np.argmax(np.asarray(logits[0])))
-        req.out_tokens.append(nxt)
+        tr = self.trace
+        with tr.span("engine.sync", op="serve"):
+            first = np.asarray(logits[0])
+        req.out_tokens.append(int(np.argmax(first)))
         if req.first_token_at is None:
             req.first_token_at = self.clock()
             req.last_token_at = req.first_token_at
             ttft = req.first_token_at - req.submitted_at
             self.metrics.observe(f"serve.ttft.{req.tenant}", ttft)
-            tr = self.trace
             if tr.enabled:
                 tr.event("ttft", tenant=req.tenant, op="serve",
                          req=req.req_id, ttft_s=ttft)
@@ -284,6 +285,8 @@ class ServeEngine:
             return None                           # rwkv: O(1) state
         k = jnp.asarray(cache["k"])[:, 0, :length]   # [L, len, KV, hd]
         v = jnp.asarray(cache["v"])[:, 0, :length]
+        # two slices, then their stack, each a copy on HBM
+        self.kv.count_copy(2 * (k.nbytes + v.nbytes))
         return jnp.stack([k, v], axis=1)          # [L, 2, len, KV, hd]
 
     # ------------------------------------------------------------- decode
@@ -362,7 +365,10 @@ class ServeEngine:
                 if req.state == "preempted":
                     self.kv.schedule_swap_in(req.seq_id)  # LMB -> onboard
                 else:
-                    self._prefill(req)
+                    with self.trace.span("engine.prefill", op="serve",
+                                         req=req.req_id,
+                                         tokens=len(req.prompt)):
+                        self._prefill(req)
             except OutOfMemory:
                 # pool too degraded to hold the KV (e.g. expander failed
                 # with no spare): cancel instead of crashing the engine
@@ -417,17 +423,32 @@ class ServeEngine:
         remains as the reference mode.  Token streams are byte-identical
         between the two.  When tracing is on, the round runs under a
         ``serve.round`` span whose children carry per-sequence TTFT and
-        inter-token events."""
+        inter-token events; its ``hbm_copy_bytes`` arg is the round's
+        share of the KV store's ``kv.hbm_copy_bytes`` counter.  A round
+        that starts while a JAX profiler session records is traced even
+        with tracing off, and its spans land in the profile."""
         impl = (self._step_pipelined if self.ecfg.pipeline
                 else self._step_phased)
         tr = self.trace
-        if not tr.enabled:
+        if tr.enabled:
+            return self._traced_step(impl, tr)
+        if not profiling():
             return impl()
+        tr.enabled = True
+        try:
+            return self._traced_step(impl, tr)
+        finally:
+            tr.enabled = False
+
+    def _traced_step(self, impl, tr: SpanTracer) -> int:
+        copied = self.kv.copied_bytes()
         with tr.span("serve.round", op="serve", active=len(self.active),
                      waiting=len(self.waiting),
                      mode=("pipelined" if self.ecfg.pipeline
-                           else "phased")):
-            return impl()
+                           else "phased")) as args:
+            finished = impl()
+            args["hbm_copy_bytes"] = self.kv.copied_bytes() - copied
+        return finished
 
     def _step_phased(self) -> int:
         """Strictly-phased reference order: admit, schedule this round's
@@ -451,7 +472,8 @@ class ServeEngine:
         request waits an extra round versus the phased order."""
         self._admit()                      # catch-up: post-tail arrivals
         finished, round_dt = self._decode_round()
-        self._round_tail(round_dt)
+        with self.trace.span("engine.tail", op="serve"):
+            self._round_tail(round_dt)
         return finished
 
     def _round_tail(self, round_dt: float) -> None:
@@ -572,17 +594,25 @@ class ServeEngine:
                 finished += 1
                 continue
             live.append((slot, req))
+        tr = self.trace
         if live:
             try:
-                view = self.kv.decode_view([r.seq_id for _, r in live],
-                                           self._max_pages)
-                toks = jnp.asarray([[r.out_tokens[-1]] for _, r in live],
-                                   jnp.int32)
-                logits, pool = self._paged_fn(
-                    self.params, view.pool, jnp.asarray(view.tables),
-                    jnp.asarray(view.lengths), toks)
-                logits = np.asarray(logits)
-                self.kv.commit_decode(view, pool)
+                with tr.span("decode.paged", op="serve") as args:
+                    view = self.kv.decode_view(
+                        [r.seq_id for _, r in live], self._max_pages)
+                    if args is not None:
+                        args.update(batch=len(live), pages=len(view.pages),
+                                    pool=int(view.pool.shape[0]))
+                    toks = jnp.asarray(
+                        [[r.out_tokens[-1]] for _, r in live], jnp.int32)
+                    logits, pool = self._paged_fn(
+                        self.params, view.pool, jnp.asarray(view.tables),
+                        jnp.asarray(view.lengths), toks)
+                    # the step returns a whole new pool (not donated)
+                    self.kv.count_copy(pool.nbytes)
+                    with tr.span("engine.sync", op="serve"):
+                        logits = np.asarray(logits)
+                    self.kv.commit_decode(view, pool)
             except OutOfMemory:
                 # the pool shrank under us (failover mid-decode): the
                 # round's working set can no longer be materialized —
@@ -594,26 +624,21 @@ class ServeEngine:
                 live = []
             else:
                 self.paged_rounds += 1
-                tr = self.trace
-                if tr.enabled:
-                    tr.event("decode.paged", op="serve",
-                             batch=len(live), pages=len(view.pages),
-                             pool=int(view.pool.shape[0]))
-        for i, (slot, req) in enumerate(live):
-            nxt = int(np.argmax(logits[i]))
-            req.out_tokens.append(nxt)
-            now = self.clock()
-            if req.last_token_at is not None:
-                gap = now - req.last_token_at
-                self.metrics.observe(f"serve.itl.{req.tenant}", gap)
-                tr = self.trace
-                if tr.enabled:
-                    tr.event("token", tenant=req.tenant, op="serve",
-                             req=req.req_id, gap_s=gap)
-            req.last_token_at = now
-            if len(req.out_tokens) >= req.max_new_tokens:
-                self._finish_active(slot, req)
-                finished += 1
+        with tr.span("engine.emit", op="serve"):
+            for i, (slot, req) in enumerate(live):
+                nxt = int(np.argmax(logits[i]))
+                req.out_tokens.append(nxt)
+                now = self.clock()
+                if req.last_token_at is not None:
+                    gap = now - req.last_token_at
+                    self.metrics.observe(f"serve.itl.{req.tenant}", gap)
+                    if tr.enabled:
+                        tr.event("token", tenant=req.tenant, op="serve",
+                                 req=req.req_id, gap_s=gap)
+                req.last_token_at = now
+                if len(req.out_tokens) >= req.max_new_tokens:
+                    self._finish_active(slot, req)
+                    finished += 1
         if self.ecfg.round_time_s is not None:
             return finished, (self.ecfg.round_time_s if self.active
                               or finished else 0.0)

@@ -14,6 +14,13 @@ of all layers' K+V at once) and stored as LinkedBuffer logical pages:
 
 Layout per logical page: [L, 2, page_tokens, KV, hd] (K and V stacked) —
 one DMA per page move, layer-major so a layer-by-layer decode can stream.
+
+Every array op the KV path runs on HBM — gathers, pads, stacks, slices,
+the onboard pool's scatters (the whole pool: the update is not donated)
+and the pool the decode step returns — adds the bytes it writes to the
+host registry's ``kv.hbm_copy_bytes`` counter, computed from shapes at
+the call.  With tracing on, ``kv.append``, ``kv.view`` and ``kv.commit``
+spans cover the store's three data-path calls.
 """
 
 from __future__ import annotations
@@ -30,6 +37,9 @@ from repro.core.buffer import LinkedBuffer
 from repro.core.client import LMBSystem
 from repro.core.offload import TierExecutor, stack_pages
 from repro.core.overlap import OverlapScheduler
+
+#: counter of the bytes the KV path's array ops write on HBM
+HBM_COPY_BYTES = "kv.hbm_copy_bytes"
 
 
 @dataclasses.dataclass
@@ -94,6 +104,8 @@ class PagedKVStore:
             dtype=jnp.dtype(cfg.dtype), onboard_pages=onboard_pages,
             policy="cost", prefetch_depth=prefetch_depth,
             overlap=overlap, compress_lmb=compress_cold)
+        self.metrics = host.metrics
+        self.buf.executor.hbm_meter = self.count_copy
         self._seqs: Dict[int, SeqPages] = {}
         self._next_id = 0
 
@@ -106,6 +118,14 @@ class PagedKVStore:
 
     def seq(self, sid: int) -> SeqPages:
         return self._seqs[sid]
+
+    def count_copy(self, nbytes: int) -> None:
+        """Count ``nbytes`` written on HBM by an array op of the KV path."""
+        self.metrics.inc(HBM_COPY_BYTES, nbytes)
+
+    def copied_bytes(self) -> float:
+        """The ``kv.hbm_copy_bytes`` counter (every store of the registry)."""
+        return self.metrics.counter(HBM_COPY_BYTES)
 
     def free_seq(self, sid: int) -> None:
         for p in self._seqs[sid].pages:
@@ -132,6 +152,11 @@ class PagedKVStore:
         ``write_many`` burst — a multi-page prefill slab costs one
         coalesced transfer per LMB chunk instead of a read/write pair
         per page."""
+        with self.buf.trace.span("kv.append", op="demand",
+                                 tokens=kv.shape[2]):
+            self._append_tokens(sid, kv)
+
+    def _append_tokens(self, sid: int, kv: jax.Array) -> None:
         seq = self._seqs[sid]
         T = kv.shape[2]
         if T == 0:
@@ -153,6 +178,7 @@ class PagedKVStore:
             # no stack/batch machinery on the hottest per-token path
             page, off, take, _ = segs[0]
             cur = self.buf.read(page)
+            self.count_copy(cur.nbytes)           # the updated page
             self.buf.write(page, jax.lax.dynamic_update_slice_in_dim(
                 cur, kv, off, axis=2))
             seq.length = length
@@ -163,6 +189,9 @@ class PagedKVStore:
             jax.lax.dynamic_update_slice_in_dim(
                 cur[i], kv[:, :, done:done + take], off, axis=2)
             for i, (page, off, take, done) in enumerate(segs)]
+        # per page: its row of cur and the updated page; the slab's
+        # slices; then the stack of the updated pages
+        self.count_copy(3 * cur.nbytes + kv.nbytes)
         self.buf.write_many(pages, stack_pages(updated))
         seq.length = length
 
@@ -291,6 +320,10 @@ class PagedKVStore:
         capacity), and page tables rewritten into pool-index space for
         the compiled step.  Active sequences must not share a tail page
         (the engine never forks a mid-flight sequence)."""
+        with self.buf.trace.span("kv.view", op="demand", batch=len(sids)):
+            return self._decode_view(sids, max_pages)
+
+    def _decode_view(self, sids: List[int], max_pages: int) -> DecodeView:
         for sid in sids:
             self.ensure_tail_page(sid)
         tables, lengths = self.page_tables(sids, max_pages)
@@ -310,6 +343,7 @@ class PagedKVStore:
             pool = jnp.concatenate(
                 [pool, jnp.zeros((cap - n,) + self.page_shape,
                                  pool.dtype)])
+            self.count_copy(pool.nbytes // cap * (2 * cap - n))
         pool_tables = np.full_like(tables, -1)
         mapped = tables >= 0
         pool_tables[mapped] = [index[p] for p in tables[mapped].tolist()]
@@ -328,7 +362,10 @@ class PagedKVStore:
         changed (the step scatters the new token's K/V there), so ONE
         ``write_many`` burst covers the whole batch, and each sequence
         advances by the token it just stored."""
-        rows = pool[np.asarray(view.tail_index, np.int64)]
-        self.buf.write_many(view.tail_pages, rows)
-        for sid in view.sids:
-            self._seqs[sid].length += 1
+        with self.buf.trace.span("kv.commit", op="demand",
+                                 batch=len(view.sids)):
+            rows = pool[np.asarray(view.tail_index, np.int64)]
+            self.count_copy(rows.nbytes)
+            self.buf.write_many(view.tail_pages, rows)
+            for sid in view.sids:
+                self._seqs[sid].length += 1

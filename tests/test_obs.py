@@ -132,6 +132,46 @@ class TestSpanTracer:
         tr.add("b", 0.0, 1.0)
         assert [s.name for s in tr.spans()] == ["b"]
 
+    def test_span_block_adds_args_before_close(self):
+        tr = SpanTracer()
+        with tr.span("decode.paged", op="serve") as args:
+            args.update(batch=3, nbytes=64)
+        (s,) = tr.spans()
+        assert (s.op, s.nbytes, s.args) == ("serve", 64, {"batch": 3})
+        with SpanTracer(enabled=False).span("decode.paged") as args:
+            assert args is None
+
+    def test_spans_land_in_the_profiler_trace(self, tmp_path):
+        """An enabled span is a profiler annotation: the JAX profile's
+        host plane holds it by name, as ``bench.trace`` flattens it.  A
+        disabled tracer records nothing and opens no annotation."""
+        import jax
+
+        from bench.trace import HOST_PLANE, events_from_xplane
+        from repro.obs import profiling
+
+        on, off = SpanTracer(), SpanTracer(enabled=False)
+        assert not profiling()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert profiling()
+            with on.span("engine.sync"):
+                with on.span("kv.view"):
+                    jnp.ones(4).block_until_ready()
+            with off.span("engine.emit"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        assert not profiling()
+        host = {e[2]: e for e in events_from_xplane(str(tmp_path))
+                if e[0] == HOST_PLANE}
+        assert "engine.sync" in host and "kv.view" in host
+        assert "engine.emit" not in host
+        outer, inner = host["engine.sync"], host["kv.view"]
+        assert outer[3] <= inner[3] and inner[4] <= outer[4]
+        assert [s.name for s in on.spans()] == ["kv.view", "engine.sync"]
+        assert len(off) == 0
+
 
 # --------------------------------------------------------------- export
 def _sample_spans():

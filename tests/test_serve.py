@@ -455,6 +455,83 @@ def test_paged_decode_serves_identical_tokens(served):
     assert all(r._cache is None for r in paged_eng.requests.values())
 
 
+def test_paged_round_spans_nest_and_count_hbm_copies(served):
+    """With tracing on, a paged round's spans nest as the chip path
+    runs: decode.paged under serve.round, kv.view under it, the view's
+    read_many burst under that, and every executor op under a
+    LinkedBuffer call.  The round's kv.hbm_copy_bytes is the bytes of
+    its HBM array ops, by hand from the shapes."""
+    from repro.serve.kv_cache import HBM_COPY_BYTES
+
+    eng = make_engine(served, trace=True)       # 2 slots, 8 onboard pages
+    for n in (5, 13):                           # 1 and 2 pages of 8 tokens
+        eng.submit(SubmitSpec(prompt=np.arange(1, n + 1), max_new_tokens=6))
+    eng.step()                                  # both prefills, a round
+    mark = len(eng.trace.spans())
+    before = eng.metrics.counter(HBM_COPY_BYTES)
+    eng.step()               # lengths 6 and 14: no new page, no fault
+    copied = eng.metrics.counter(HBM_COPY_BYTES) - before
+
+    spans = eng.trace.spans()
+    by_id = {s.span_id: s for s in spans}
+    parent = lambda s: by_id[s.parent_id].name  # noqa: E731
+    (rnd,) = [s for s in spans[mark:] if s.name == "serve.round"]
+    (dec,) = [s for s in spans[mark:] if s.name == "decode.paged"]
+    assert dec.parent_id == rnd.span_id
+    assert dec.args == {"batch": 2, "pages": 3, "pool": 8}
+    (view,) = [s for s in spans[mark:] if s.name == "kv.view"]
+    assert view.parent_id == dec.span_id
+    assert [parent(s) for s in spans[mark:]
+            if s.name == "lmb.read_many"] == ["kv.view"]
+    for name in ("engine.sync", "kv.commit"):
+        assert [parent(s) for s in spans[mark:] if s.name == name] == [
+            "decode.paged"]
+    assert {parent(s) for s in spans[mark:]
+            if s.name in ("engine.emit", "engine.tail")} == {"serve.round"}
+
+    def ancestors(s):
+        while s.parent_id is not None:
+            s = by_id[s.parent_id]
+            yield s.name
+
+    execs = [s for s in spans if s.name.startswith("exec.")]
+    assert execs
+    assert all(any(n.startswith("lmb.") for n in ancestors(s))
+               for s in execs)
+
+    # gather of the 3-page union, 5 zero pages and the padded pool of 8,
+    # the 8-page pool the step returns, the 2 tail rows and their
+    # gather in write_many, then the scatter over all 8 onboard pages
+    pb = eng.kv.buf.page_bytes
+    assert copied == pb * (3 + 5 + 8 + 8 + 2 + 2 + 8)
+    assert rnd.args["hbm_copy_bytes"] == copied
+
+
+def test_rounds_under_a_profiler_session_are_traced(served, tmp_path):
+    """With tracing off, the rounds that run while a JAX profiler
+    session records are traced into the engine's tracer, which is off
+    again after each; rounds outside the session record nothing."""
+    eng = make_engine(served)
+    tr = eng.trace
+    assert not tr.enabled
+    tr.clear()
+    eng.submit(SubmitSpec(prompt=np.arange(1, 10), max_new_tokens=4))
+    eng.step()
+    assert len(tr) == 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.step()
+        assert not tr.enabled
+    finally:
+        jax.profiler.stop_trace()
+        traced = tr.spans()
+        tr.clear()
+    eng.run(50)
+    assert len(tr) == 0
+    assert [s.name for s in traced if s.parent_id is None] == ["serve.round"]
+    assert "decode.paged" in {s.name for s in traced}
+
+
 def test_paged_decode_spills_past_onboard(served):
     """Paged decode with a working set far beyond the onboard tier: the
     DecodeView's coalesced read bursts wave through onboard capacity and
