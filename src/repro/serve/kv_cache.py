@@ -15,17 +15,24 @@ of all layers' K+V at once) and stored as LinkedBuffer logical pages:
 Layout per logical page: [L, 2, page_tokens, KV, hd] (K and V stacked) —
 one DMA per page move, layer-major so a layer-by-layer decode can stream.
 
-Every array op the KV path runs on HBM — gathers, pads, stacks, slices,
-the onboard pool's scatters (the whole pool: the update is not donated)
-and the pool the decode step returns — adds the bytes it writes to the
-host registry's ``kv.hbm_copy_bytes`` counter, computed from shapes at
-the call.  With tracing on, ``kv.append``, ``kv.view`` and ``kv.commit``
-spans cover the store's three data-path calls.
+A prefill slab becomes its pages in ONE compiled call
+(:func:`pack_slab`: pad to whole pages, reshape, move the page axis
+first), written with one ``write_many`` burst.  Pages the slab opens
+are fresh, so nothing is read for them; only a slab that starts
+mid-page reads that one page, whose live tokens the same call keeps.
+
+Every array op the KV path runs on HBM — gathers, pads, stacks, the
+packed slab, the onboard pool's scatters (the whole pool: the update is
+not donated) and the pool the decode step returns — adds the bytes it
+writes to the host registry's ``kv.hbm_copy_bytes`` counter, computed
+from shapes at the call.  With tracing on, ``kv.append``, ``kv.view``
+and ``kv.commit`` spans cover the store's three data-path calls.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional
 
 import jax
@@ -35,11 +42,36 @@ import numpy as np
 from repro.core.api import LMBHost
 from repro.core.buffer import LinkedBuffer
 from repro.core.client import LMBSystem
-from repro.core.offload import TierExecutor, stack_pages
+from repro.core.offload import TierExecutor
 from repro.core.overlap import OverlapScheduler
 
 #: counter of the bytes the KV path's array ops write on HBM
 HBM_COPY_BYTES = "kv.hbm_copy_bytes"
+#: counters of the multi-page append: pages :func:`pack_slab` built, and
+#: pages read first because the slab starts in the middle of one
+APPEND_PAGES_PACKED = "kv.append_pages_packed"
+APPEND_PAGES_READ = "kv.append_pages_read"
+
+
+@functools.partial(jax.jit, static_argnames=("n_pages", "page_tokens"))
+def pack_slab(kv: jax.Array, off, head: Optional[jax.Array] = None, *,
+              n_pages: int, page_tokens: int) -> jax.Array:
+    """Lay a slab ``kv [L, 2, T, KV, hd]`` out as its KV pages
+    ``[n_pages, L, 2, page_tokens, KV, hd]``, the slab's first token at
+    token ``off`` of the first page, in one device program.  Slots the
+    slab does not cover are zeros, except that the first page keeps
+    ``head``'s tokens before ``off`` when ``head`` (that page's current
+    contents) is given.  ``off`` is traced: one program per slab shape
+    and page count, whatever the offset."""
+    L, two, _, KV, hd = kv.shape
+    flat = jnp.zeros((L, two, n_pages * page_tokens, KV, hd), kv.dtype)
+    flat = jax.lax.dynamic_update_slice_in_dim(flat, kv, off, axis=2)
+    pages = jnp.moveaxis(
+        flat.reshape(L, two, n_pages, page_tokens, KV, hd), 2, 0)
+    if head is not None:
+        live = (jnp.arange(page_tokens) < off)[:, None, None]
+        pages = pages.at[0].set(jnp.where(live, head, pages[0]))
+    return pages
 
 
 @dataclasses.dataclass
@@ -133,67 +165,74 @@ class PagedKVStore:
         del self._seqs[sid]
 
     def fork(self, sid: int) -> int:
-        """Zero-copy prefix share: new sequence maps the same pages (COW
-        on write) — the Table-2 ``share`` scenario.  One batched
-        ``share_many`` call for the whole prefix."""
+        """Prefix share — the Table-2 ``share`` scenario: the new
+        sequence maps the parent's whole pages zero-copy, with one
+        batched ``share_many`` call.  A partially filled tail page, which
+        the next append of either sequence writes, is copied into a page
+        of the fork's own: a write through a shared page would copy it
+        under the logical id both sequences map, and each would then see
+        the other's tokens."""
         new = self.new_seq()
         src = self._seqs[sid]
         dst = self._seqs[new]
-        dst.pages = self.buf.share_many(src.pages)
+        full = src.length // self.page_tokens
+        dst.pages = self.buf.share_many(src.pages[:full])
+        if src.length % self.page_tokens:
+            tail = self.buf.read(src.pages[full])
+            dst.pages.extend(self.buf.append_pages(1))
+            self.buf.write(dst.pages[-1], tail)
         dst.length = src.length
         return new
 
     # ------------------------------------------------------------ data path
     def append_tokens(self, sid: int, kv: jax.Array) -> None:
-        """kv [L, 2, T, KV, hd] for T new tokens (T <= page_tokens from
-        decode; prefill calls in page-sized slabs).  Batched data path:
-        the touched pages are planned up front, faulted in with ONE
-        ``read_many`` burst, updated, and written back with ONE
-        ``write_many`` burst — a multi-page prefill slab costs one
-        coalesced transfer per LMB chunk instead of a read/write pair
-        per page."""
+        """kv [L, 2, T, KV, hd] for T new tokens (one from decode, a
+        whole prompt from prefill).  A slab within one page is a plain
+        read, update and write of that page.  A slab over several pages
+        is laid out as its pages by ONE compiled :func:`pack_slab` call
+        and written with ONE ``write_many`` burst (one coalesced
+        transfer per LMB chunk).  The pages it opens are fresh, so
+        nothing is read for them; a slab that starts mid-page reads that
+        first page (faulting it in from the LMB tier if it is there), and
+        the same call keeps its live tokens.  The ``kv.append`` span
+        carries ``pages`` (written) and ``read`` (read first)."""
         with self.buf.trace.span("kv.append", op="demand",
-                                 tokens=kv.shape[2]):
-            self._append_tokens(sid, kv)
+                                 tokens=kv.shape[2]) as args:
+            pages, read = self._append_tokens(sid, kv)
+            if args is not None:
+                args.update(pages=pages, read=read)
 
-    def _append_tokens(self, sid: int, kv: jax.Array) -> None:
+    def _append_tokens(self, sid: int, kv: jax.Array) -> tuple:
+        """Append the slab; returns (pages written, pages read)."""
         seq = self._seqs[sid]
         T = kv.shape[2]
         if T == 0:
-            return                        # empty slab: scalar loop no-op
-        # plan the page segments this slab touches
-        segs = []                         # (page, token offset, take, src)
-        done, length = 0, seq.length
-        while done < T:
-            off = length % self.page_tokens
-            if off == 0:
-                seq.pages.extend(self.buf.append_pages(1))
-            page = seq.pages[length // self.page_tokens]
-            take = min(self.page_tokens - off, T - done)
-            segs.append((page, off, take, done))
-            length += take
-            done += take
-        if len(segs) == 1:
+            return 0, 0                   # empty slab: scalar loop no-op
+        pt = self.page_tokens
+        off, first = seq.length % pt, seq.length // pt
+        n = -(-(off + T) // pt)           # pages the slab touches
+        opened = n - 1 if off else n      # each page it enters at token 0
+        if opened:
+            seq.pages.extend(self.buf.append_pages(opened))
+        pages = seq.pages[first:first + n]
+        if n == 1:
             # decode path: one page per token — plain scalar read/write,
             # no stack/batch machinery on the hottest per-token path
-            page, off, take, _ = segs[0]
-            cur = self.buf.read(page)
+            cur = self.buf.read(pages[0])
             self.count_copy(cur.nbytes)           # the updated page
-            self.buf.write(page, jax.lax.dynamic_update_slice_in_dim(
+            self.buf.write(pages[0], jax.lax.dynamic_update_slice_in_dim(
                 cur, kv, off, axis=2))
-            seq.length = length
-            return
-        pages = [s[0] for s in segs]
-        cur = self.buf.read_many(pages)        # one coalesced fault burst
-        updated = [
-            jax.lax.dynamic_update_slice_in_dim(
-                cur[i], kv[:, :, done:done + take], off, axis=2)
-            for i, (page, off, take, done) in enumerate(segs)]
-        # per page: its row of cur and the updated page; the slab's
-        # slices; then the stack of the updated pages
-        self.count_copy(3 * cur.nbytes + kv.nbytes)
-        self.buf.write_many(pages, stack_pages(updated))
-        seq.length = length
+            seq.length += T
+            return 1, 1
+        read = int(off > 0)
+        head = self.buf.read(pages[0]) if read else None
+        packed = pack_slab(kv, off, head, n_pages=n, page_tokens=pt)
+        self.count_copy(packed.nbytes)            # the packed pages
+        self.metrics.inc(APPEND_PAGES_PACKED, n)
+        self.metrics.inc(APPEND_PAGES_READ, read)
+        self.buf.write_many(pages, packed)
+        seq.length += T
+        return n, read
 
     def gather_seq(self, sid: int) -> jax.Array:
         """Materialize a sequence's KV [L, 2, seq.length, KV, hd] onboard

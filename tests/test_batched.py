@@ -508,33 +508,151 @@ def test_meter_transfer_many_merges_per_link():
     assert system.fm.meter_calls() - calls0 == 2
 
 
-def test_kv_append_slab_equals_token_loop():
-    """One multi-page prefill slab == the same tokens appended one by
-    one (the batched planner must land every token in the same page
-    cell)."""
+PT = 4                                        # tokens per KV page
+
+
+def kv_store(onboard_pages=8, tracer=None, page_tokens=PT):
+    """A reduced-width PagedKVStore on a fresh system; ``tracer``
+    becomes the fabric's (and so the store's) span tracer."""
     from repro.configs.base import get_config
     from repro.serve.kv_cache import PagedKVStore
     cfg = get_config("qwen2-1.5b").reduced()
-    stores = []
-    for _ in range(2):
-        system = system_for("tpu0", host_id="h0", pool_gib=1,
-                            page_bytes=4096, metrics=Metrics())
-        stores.append(PagedKVStore(cfg=cfg, system=system,
-                                   device_id="tpu0", page_tokens=4,
-                                   onboard_pages=8))
-    L, KV, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
-    T = 11                                    # 3 pages, last partial
+    system = system_for("tpu0", host_id="h0", pool_gib=1,
+                        page_bytes=4096, metrics=Metrics())
+    if tracer is not None:
+        system.host().fm.tracer = tracer
+    return PagedKVStore(cfg=cfg, system=system, device_id="tpu0",
+                        page_tokens=page_tokens,
+                        onboard_pages=onboard_pages)
+
+
+def kv_tokens(store, rng, n):
+    L, _, _, KV, hd = store.page_shape
+    return jnp.asarray(rng.standard_normal((L, 2, n, KV, hd)),
+                       store.buf.dtype)
+
+
+def gathered(store, sid):
+    return np.asarray(store.gather_seq(sid))
+
+
+@pytest.mark.parametrize("slab", [1, PT - 1, PT, PT + 1, 3 * PT + 3])
+@pytest.mark.parametrize("start", [0, 1, PT - 1, PT])
+@pytest.mark.parametrize("kind", ["onboard", "spill", "fork"])
+def test_kv_append_slab_equals_token_loop(kind, start, slab):
+    """One slab appended to a sequence of ``start`` tokens == the same
+    tokens appended one by one: every token lands in the same page cell.
+    ``spill``: 2 onboard pages, and a filler sequence pushes the
+    sequence's pages to the LMB tier first, so a mid-page slab faults
+    its first page in.  ``fork``: the slab goes to a fork of the
+    sequence (its partial tail copied, not written through the share),
+    and the parent is unchanged by it, and the fork by the parent's next
+    token."""
+    rng = np.random.default_rng(1000 * start + slab)
+    onboard = 2 if kind == "spill" else 8
+    stores = [kv_store(onboard) for _ in range(2)]
+    prefix = kv_tokens(stores[0], rng, start)
+    filler = kv_tokens(stores[0], rng, 3 * PT)
+    kv = kv_tokens(stores[0], rng, slab)
+    more = kv_tokens(stores[0], rng, 1)
+    sids, parents = [], []
+    for store in stores:
+        sid = store.new_seq()
+        store.append_tokens(sid, prefix)
+        if kind == "spill":
+            store.append_tokens(store.new_seq(), filler)
+            if start % PT:
+                assert store.buf.tier_of(store.seq(sid).pages[-1]) == "lmb"
+        if kind == "fork":
+            parents.append((sid, gathered(store, sid)))
+            sid = store.fork(sid)
+        sids.append(sid)
+    stores[0].append_tokens(sids[0], kv)              # one slab
+    for t in range(slab):                             # token loop
+        stores[1].append_tokens(sids[1], kv[:, :, t:t + 1])
+    for store, sid in zip(stores, sids):
+        assert store.seq(sid).length == start + slab
+        assert len(store.seq(sid).pages) == -(-(start + slab) // PT)
+    want = gathered(stores[1], sids[1])
+    assert np.array_equal(gathered(stores[0], sids[0]), want)
+    for store, sid, (parent, before) in zip(stores, sids, parents):
+        assert np.array_equal(gathered(store, parent), before)
+        store.append_tokens(parent, more)
+        assert np.array_equal(gathered(store, sid), want)
+    for store in stores:
+        store.buf.check_invariants()
+
+
+def test_kv_append_counts_packed_pages_reads_and_hbm_bytes():
+    """A prefill slab over n pages: the packing call builds n pages and
+    nothing is read when the slab starts a sequence; one page is read
+    when it starts mid-page.  The counters and the ``kv.append`` span's
+    args say so, and ``kv.hbm_copy_bytes`` is the bytes of the append's
+    HBM array ops, by hand from the shapes."""
+    from repro.obs.trace import SpanTracer
+    from repro.serve.kv_cache import (APPEND_PAGES_PACKED,
+                                      APPEND_PAGES_READ, HBM_COPY_BYTES)
+    tr = SpanTracer()
+    P = 16                                    # onboard pages: the pool
+    store = kv_store(P, tracer=tr)
+    m, pb = store.metrics, store.buf.page_bytes
     rng = np.random.default_rng(0)
-    kv = jnp.asarray(rng.standard_normal((L, 2, T, KV, hd)),
-                     jnp.dtype(cfg.dtype))
-    sa = stores[0].new_seq()
-    stores[0].append_tokens(sa, kv)           # one slab
-    sb = stores[1].new_seq()
-    for t in range(T):                        # token loop
-        stores[1].append_tokens(sb, kv[:, :, t:t + 1])
-    assert stores[0].seq(sa).length == stores[1].seq(sb).length == T
-    assert np.array_equal(np.asarray(stores[0].gather_seq(sa)),
-                          np.asarray(stores[1].gather_seq(sb)))
-    forked = stores[0].fork(sa)
-    assert np.array_equal(np.asarray(stores[0].gather_seq(forked)),
-                          np.asarray(stores[0].gather_seq(sa)))
+
+    def append(sid, n_tokens):
+        before = (m.counter(HBM_COPY_BYTES), m.counter(APPEND_PAGES_PACKED),
+                  m.counter(APPEND_PAGES_READ))
+        store.append_tokens(sid, kv_tokens(store, rng, n_tokens))
+        after = (m.counter(HBM_COPY_BYTES), m.counter(APPEND_PAGES_PACKED),
+                 m.counter(APPEND_PAGES_READ))
+        (span,) = [s for s in tr.spans() if s.name == "kv.append"][-1:]
+        return [b - a for a, b in zip(before, after)], span.args
+
+    # fresh: 3 pages.  The packed pages; in write_many the fault of the
+    # 3 fresh pages (a zero page, their stack, the scatter over the
+    # pool), the gather of the rows, the scatter over the pool
+    sid = store.new_seq()
+    (copied, packed, read), args = append(sid, 3 * PT - 1)
+    assert (packed, read) == (3, 0)
+    assert args == {"tokens": 3 * PT - 1, "pages": 3, "read": 0}
+    assert copied == pb * (3 + (1 + 3 + P) + 3 + P)
+    # mid-page: 1 + 2 * PT tokens into the third page's last slot, 3
+    # pages.  The read of that page; the packed pages; in write_many the
+    # fault of the 2 fresh pages, the gather of the 3 rows, the scatter
+    (copied, packed, read), args = append(sid, 1 + 2 * PT)
+    assert (packed, read) == (3, 1)
+    assert args == {"tokens": 1 + 2 * PT, "pages": 3, "read": 1}
+    assert copied == pb * (1 + 3 + (1 + 2 + P) + 3 + P)
+    assert store.seq(sid).length == 5 * PT
+
+
+def test_kv_append_builds_one_pack_program_per_slab_shape():
+    """Slabs of one length appended to new sequences, and at two
+    offsets inside a page: :func:`pack_slab` builds a program for the
+    first slab of each shape and page count, and none after (the offset
+    is traced, not static)."""
+    import jax
+    from repro.serve.kv_cache import pack_slab
+    built = []
+
+    def on_compile(event, secs, fun_name=None, **_):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and fun_name == f"jit({pack_slab.__name__})"):
+            built.append(fun_name)
+
+    pt = 5                  # a page size no other test packs with
+    store = kv_store(64, page_tokens=pt)
+    rng = np.random.default_rng(0)
+    T = 2 * pt + 2                            # 3 pages from offsets 0-3
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        counts = []
+        for start in (0, 0, 0, 1, 3, 1):
+            sid = store.new_seq()
+            if start:
+                store.append_tokens(sid, kv_tokens(store, rng, start))
+            store.append_tokens(sid, kv_tokens(store, rng, T))
+            counts.append(len(built))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    # one build for the fresh slab, one for the slab with a page to merge
+    assert counts == [1, 1, 1, 2, 2, 2]
