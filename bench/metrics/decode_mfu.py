@@ -3,17 +3,16 @@ the decode rounds inside the traced window (projections, attention over
 each row's context, the head) over those rounds' wall time on the host
 clock and the chip's bf16 peak, in percent.  The traced rounds alone:
 the window's wall time also holds the profiler's stop, which is no
-work of the program."""
-
-from bench import flops
+work of the program.  FLOPs: the configuration's architecture module
+(``decode_token_flops``)."""
 
 
 def read(run):
     steps = run.traced_steps
     if not steps:
         return None
-    c = run.cell.config
-    total = sum(flops.decode_token_flops(c, n)
+    c, arch = run.cell.config, run.cell.arch
+    total = sum(arch.decode_token_flops(c, n)
                 for s in steps for n in s.contexts)
     span = steps[-1].t1 - steps[0].t0
     if total == 0 or span <= 0:

@@ -20,8 +20,9 @@ The run:
 4. the check (:func:`judge`): no request failed, nothing was built in
    the window, and a sample of the requests finished in the window,
    drawn from the seed and holding the longest, lies within the cell's
-   limit of the float32 reference (``bench/reference.py``), run once
-   the program's state is freed.
+   limit of the float32 reference of the configuration's architecture
+   module (``bench/arch/<arch>.py``), run once the program's state is
+   freed.
 
 The last line of standard output is the result; the checks, each
 number beside its limit, are the last lines of standard error.  Off a
@@ -43,6 +44,7 @@ import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Dict, List, Optional  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -64,6 +66,9 @@ REPLAY_MARGIN = 1.2
 LMB_POOL_GIB = 4
 #: where a traced run's profile is written, read and deleted
 TRACE_DIR = os.path.join(ROOT, ".bench_runs", "trace")
+#: what every architecture module (``bench/arch/<arch>.py``) provides
+ARCH_API = ("stated", "served_params", "logits", "decode_token_flops",
+            "prefill_flops", "paged_kernel_cost", "paged_layers")
 
 
 class NoChip(RuntimeError):
@@ -80,11 +85,40 @@ class Cell:
     limits: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    arch: ModuleType            # the configuration's architecture module
 
 
 def _load_json(*parts) -> Dict:
     with open(os.path.join(*parts)) as f:
         return json.load(f)
+
+
+def _load_module(name: str, path: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_arch(config: Dict, config_file: str,
+              bench_dir: str = BENCH) -> ModuleType:
+    """The module ``<bench_dir>/arch/<arch>.py`` that the configuration
+    (read from ``config_file``) names, with every function of
+    ``ARCH_API``."""
+    name = config.get("arch")
+    if not isinstance(name, str) or not name:
+        raise ValueError(f"{config_file}: names no architecture module "
+                         "(key \"arch\")")
+    path = os.path.join(bench_dir, "arch", name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"{config_file}: architecture {name!r} has no "
+                         f"module {path}")
+    mod = _load_module(f"bench_arch_{name}", path)
+    missing = [f for f in ARCH_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"{config_file}: architecture module {path} "
+                         f"lacks {missing}")
+    return mod
 
 
 def load_cell(name: str, spec: Optional[Dict] = None,
@@ -95,16 +129,18 @@ def load_cell(name: str, spec: Optional[Dict] = None,
     if not found:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     w = found[0]
+    config_file = os.path.join(bench_dir, "configs", w["config"] + ".json")
+    config = _load_json(config_file)
     e2e = [m for m in spec["end_to_end"] if name in m.get("workloads", [name])]
     shown = {m["name"] for m in e2e}
     per_layer = [m for m in spec["per_layer"]
                  if name in m.get("workloads", [name])
                  and m["moves"] in shown]
-    return Cell(name=name, chips=w["chips"],
-                config=_load_json(bench_dir, "configs", w["config"] + ".json"),
+    return Cell(name=name, chips=w["chips"], config=config,
                 mix=_load_json(bench_dir, "traffic", w["traffic"] + ".json"),
                 limits=_load_json(bench_dir, "limits", name + ".json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer,
+                arch=load_arch(config, config_file, bench_dir))
 
 
 # --------------------------------------------------------------- program
@@ -118,24 +154,16 @@ def build_program(cell: Cell, seed: int):
     from repro.models.flags import Flags
 
     c = cell.config
-    arch = dataclasses.replace(get_config(c["registered"]),
-                               **c.get("overrides", {}))
-    stated = {"d_model": c["hidden_size"], "d_ff": c["intermediate_size"],
-              "num_heads": c["num_attention_heads"],
-              "num_kv_heads": c["num_key_value_heads"],
-              "num_layers": c["num_hidden_layers"],
-              "vocab_size": c["vocab_size"], "head_dim_": c["head_dim"],
-              "rope_theta": c["rope_theta"], "norm_eps": c["rms_norm_eps"],
-              "dtype": c["dtype"], "qkv_bias": True}
-    wrong = {k: (getattr(arch, k), v) for k, v in stated.items()
-             if getattr(arch, k) != v}
+    cfg = dataclasses.replace(get_config(c["registered"]),
+                              **c.get("overrides", {}))
+    wrong = {k: (getattr(cfg, k), v) for k, v in cell.arch.stated(c).items()
+             if getattr(cfg, k) != v}
     if wrong:
         raise ValueError(f"{c['name']}: the program's config departs from "
                          f"the stated one: {wrong}")
-    model = build_model(arch, Flags(remat=False))
-    from bench import weights
-    params = weights.served_params(c, traffic.seed_parts(seed),
-                                   arch.padded_vocab)
+    model = build_model(cfg, Flags(remat=False))
+    params = cell.arch.served_params(c, traffic.seed_parts(seed),
+                                     cfg.padded_vocab)
     want = jax.eval_shape(model.init, jax.random.key(0))
     got = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
@@ -146,7 +174,7 @@ def build_program(cell: Cell, seed: int):
         raise ValueError("the program's parameter tree has changed: "
                          f"{want} != {got}")
     jax.block_until_ready(params)
-    return arch, model, params
+    return cfg, model, params
 
 
 def engine_config(cell: Cell, plan: traffic.Plan):
@@ -378,7 +406,7 @@ def sample(driver: Driver, eng, mix_check: Dict, seed: int, w0: float,
     return chosen
 
 
-def widest_gaps(c: Dict, seed: int, chosen: List[tuple],
+def widest_gaps(cell: Cell, seed: int, chosen: List[tuple],
                 control: bool = False) -> Dict[str, float]:
     """The widest gap by which a token lies below the float32
     reference's best logit at its position, over every sampled request:
@@ -387,12 +415,12 @@ def widest_gaps(c: Dict, seed: int, chosen: List[tuple],
     (``control``).  A token outside the vocabulary reads ``inf``."""
     seqs = [np.concatenate([p, t[:-1].astype(np.int32)]) for p, t in chosen]
     starts = [len(p) - 1 for p, _ in chosen]
-    sp = traffic.seed_parts(seed)
-    ref = reference.logits(c, sp, seqs, starts)
+    arch, c, sp = cell.arch, cell.config, traffic.seed_parts(seed)
+    ref = arch.logits(c, sp, seqs, starts)
     out = {"served": max(float(reference.gaps(r, t).max())
                          for r, (_, t) in zip(ref, chosen))}
     if control:
-        ctl = reference.logits(c, sp, seqs, starts, control=True)
+        ctl = arch.logits(c, sp, seqs, starts, control=True)
         out["control"] = max(float(reference.gaps(r, q.argmax(1)).max())
                              for r, q in zip(ref, ctl))
     return out
@@ -445,12 +473,8 @@ def check_devices(chips: int):
 
 
 def metric_reader(name: str):
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(f"bench_metric_{name}",
+                        os.path.join(BENCH, "metrics", name + ".py")).read
 
 
 def log(*a) -> None:
@@ -502,7 +526,7 @@ def _run(cell, seed, seconds, tracing, peaks, t_start, control, warm,
     mix = cell.mix
     dev = jax.devices()[0]
 
-    arch, model, params = build_program(cell, seed)
+    _, model, params = build_program(cell, seed)
     plan = traffic.plan(mix, seed, cell.config["vocab_size"])
     ecfg = engine_config(cell, plan)
     log(f"cell {cell.name}: seed={seed} menu={plan.menu} "
@@ -616,7 +640,7 @@ def _run(cell, seed, seconds, tracing, peaks, t_start, control, warm,
 
     # ----------------------------------------------------------- the check
     t_ref = time.monotonic()
-    gaps = widest_gaps(cell.config, seed, chosen, control) if chosen else {}
+    gaps = widest_gaps(cell, seed, chosen, control) if chosen else {}
     log(f"reference: {time.monotonic() - t_ref} s over {len(chosen)} "
         "requests")
     tokens = int(sum(len(t) for _, t in chosen))

@@ -3,7 +3,12 @@
 its look for a chip.  A sound run passes and builds nothing in its
 window; the fp8 control, put in the served tokens' place, a decode step
 that returns its KV state unchanged, a step that leaves out half its
-batch, and an altered token all fail."""
+batch, and an altered token all fail.
+
+The window is long enough, even on a loaded CPU, to finish the requests
+that fill the sample (``tiny_limits.json``: 160 tokens or 16 requests,
+80 tokens at least): the control's departure shows at some of that many
+positions, where a short window's few could all agree."""
 
 import json
 import os
@@ -15,6 +20,8 @@ from bench import run
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 SEEDS = (2**33 + 5, 41)
+#: seconds of CPU window
+WINDOW_S = 4.0
 
 
 def load(name):
@@ -23,16 +30,17 @@ def load(name):
 
 
 def tiny_cell():
+    c = load("tiny")
     return run.Cell(
-        name="tiny", chips=1, config=load("tiny"), mix=load("tiny_closed"),
+        name="tiny", chips=1, config=c, mix=load("tiny_closed"),
         limits=load("tiny_limits"),
         end_to_end=[{"name": n, "unit": "x"} for n in
                     ("setup_s", "out_tok_s", "itl_p95_ms")],
-        per_layer=[])
+        per_layer=[], arch=run.load_arch(c, "tiny.json"))
 
 
 def go(seed=SEEDS[0], control=False):
-    return run.run_cell(tiny_cell(), seed, 1.5, False, control=control,
+    return run.run_cell(tiny_cell(), seed, WINDOW_S, False, control=control,
                         compile_cache=False)
 
 
