@@ -21,6 +21,11 @@ Capabilities:
     paper's PCIe devices don't participate in coherence, and neither do we:
     ownership transfer is explicit)
   * pin/unpin for pages a compiled step will touch (DMA in flight)
+  * **in-place access** — :meth:`onboard_slots` faults a batch onboard and
+    returns its slots in :attr:`onboard_array`, which a compiled step
+    then reads and writes in place (donating it); :meth:`replace_onboard`
+    installs the array the step returns and marks the written pages
+    dirty — no gather, no scatter
   * refcounted page sharing + copy-on-write (zero-copy prefix sharing, the
     paper's SSD→accelerator shared-buffer scenario)
   * degraded mode on expander failure (availability: fall back to
@@ -33,8 +38,9 @@ Capabilities:
     lossy (~1e-2 relative) — suited to KV caches, not optimizer state
   * **spans at the tier's boundary** — with the FM's tracer on, every
     public data-path call (:meth:`read`, :meth:`write`, :meth:`read_many`,
-    :meth:`write_many`, :meth:`append_pages`, :meth:`release`,
-    :meth:`schedule_prefetch`, :meth:`note_compute_window`) runs under an
+    :meth:`write_many`, :meth:`onboard_slots`, :meth:`replace_onboard`,
+    :meth:`append_pages`, :meth:`release`, :meth:`schedule_prefetch`,
+    :meth:`note_compute_window`) runs under an
     ``lmb.<call>`` span; ``fault``/``fault.batch``, ``evict.batch``,
     ``prefetch.burst`` and the executor's ``exec.*`` spans nest inside
   * **per-page access heat** (exponentially-decayed touch counters fed by
@@ -1070,6 +1076,64 @@ class LinkedBuffer:
                 self._pages[p].dirty = True
                 if hasattr(self.policy, "mark_dirty"):
                     self.policy.mark_dirty(p, True)
+
+    # ------------------------------------------------------- in-place access
+    @property
+    def onboard_array(self) -> jax.Array:
+        """The onboard tier's pool ``[onboard_pages, *page_shape]`` as it
+        stands: what a compiled step reads and writes in place, at the
+        slots :meth:`onboard_slots` returns."""
+        return self._onboard_pool
+
+    @_lmb_span
+    def onboard_slots(self, pages: Sequence[int],
+                      written: Sequence[int] = ()) -> Optional[List[int]]:
+        """Fault ``pages`` onboard with one batched fault, as
+        :meth:`read_many` does (the same hits, recency and prefetcher
+        observations), and return each page's onboard slot, so that a
+        compiled step can read them in place in :attr:`onboard_array`.
+        Each shared page of ``written``, which the step will write, is
+        copied first (copy on write), as :meth:`write_many` would.
+        Returns None, and touches nothing, when the distinct pages
+        cannot all co-reside onboard: the caller then gathers them with
+        :meth:`read_many`'s waves."""
+        pages = list(pages)
+        if not self._single_wave_fits(list(dict.fromkeys(pages))):
+            return None
+        for p in dict.fromkeys(written):
+            self._check(p)
+            if self._pages[p].refcount > 1:
+                self._cow(p)
+        slotmap = self._fault_in_many(pages)
+        return [slotmap[p] for p in pages]
+
+    @_lmb_span
+    def replace_onboard(self, pool: jax.Array,
+                        written: Sequence[int]) -> None:
+        """Install ``pool``, the onboard array a compiled step returned
+        after writing the pages ``written`` in place at their
+        :meth:`onboard_slots`, as the onboard tier.  The written pages
+        are accounted as :meth:`write_many`'s fault pass accounts them
+        (onboard hits, recency, prefetcher observations) and marked
+        dirty.  Nothing is copied."""
+        shape = (self.onboard_pages, *self.page_shape)
+        if pool.shape != shape or pool.dtype != self.dtype:
+            raise ValueError(
+                f"{self.name}: onboard pool {pool.shape} {pool.dtype} != "
+                f"{shape} {jnp.dtype(self.dtype)}")
+        written = list(written)
+        for p in written:
+            self._check(p)
+            if self._pages[p].tier != ONBOARD:
+                raise ValueError(
+                    f"{self.name}: page {p} left the onboard tier before "
+                    "its in-place write was installed")
+        self._onboard_pool = pool
+        self._fault_in_many(written)             # every page a hit
+        for p in dict.fromkeys(written):
+            self._pages[p].dirty = True
+            if hasattr(self.policy, "mark_dirty"):
+                self.policy.mark_dirty(p, True)
 
     def gather(self, pages: Sequence[int]) -> jax.Array:
         """Stack several logical pages (faulting them in) — kernel feed.

@@ -165,7 +165,8 @@ class TierExecutor:
         self.meter = meter
         #: charged with the bytes every array op of the data path writes
         #: on HBM: gathers, slices, stacks, and each onboard scatter the
-        #: whole pool (the update is not donated)
+        #: whole pool (an eager ``.at[].set`` writes a new pool; only a
+        #: compiled step that donates the pool writes it in place)
         self.hbm_meter = hbm_meter
 
     def count_hbm(self, nbytes: int) -> None:

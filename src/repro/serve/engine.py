@@ -129,8 +129,8 @@ class EngineConfig:
     round_time_s: Optional[float] = None
     #: decode straight from the paged KV pool: every round runs ONE
     #: batched paged-attention step over all active slots against a
-    #: DecodeView of the pool (union of the actives' pages, one
-    #: coalesced read burst) instead of a per-request dense slot cache
+    #: DecodeView of the pool (the onboard pool itself, in place, when
+    #: the actives' pages fit it) instead of a per-request dense slot cache
     #: filled by host-side gather_seq swap-in.  Token streams are
     #: byte-identical to the dense path (the decode dispatcher's
     #: numerics mirror attn_decode bitwise).  Automatically falls back
@@ -207,7 +207,10 @@ class ServeEngine:
         #: slot cache for decode); dense stays for uncovered archs
         self._use_paged = (ecfg.paged_decode
                            and model.supports_paged_decode())
-        self._paged_fn = (jax.jit(model.decode_step_paged)
+        #: the step donates its pool: the onboard pool itself when the
+        #: round runs in place, else the gathered copy
+        self._paged_fn = (jax.jit(model.decode_step_paged,
+                                  donate_argnums=(1,))
                           if self._use_paged else None)
         self._max_pages = -(-ecfg.max_seq_len // ecfg.page_tokens)
         self.paged_rounds = 0
@@ -566,10 +569,13 @@ class ServeEngine:
 
         The round builds a :class:`~repro.serve.kv_cache.DecodeView`
         (tail pages guaranteed, the actives' page union faulted onboard
-        with one coalesced burst — the round's touched-page list riding
+        with one batched fault — the round's touched-page list riding
         the same meter/prefetch accounting as every other access), runs
-        the compiled ``decode_step_paged`` once for the whole batch, and
-        commits only the tail pages back.  Token streams are
+        the compiled ``decode_step_paged`` once for the whole batch,
+        and commits before waiting on the logits: in place, the step
+        writes the new tokens' K/V straight into the donated onboard
+        pool; a union that outgrows the onboard tier is gathered, and
+        only its tail pages are written back.  Token streams are
         byte-identical to the dense per-request loop; what changed is
         the data path — no per-request dense cache, no host-side
         gather_seq swap-in.
@@ -608,11 +614,14 @@ class ServeEngine:
                     logits, pool = self._paged_fn(
                         self.params, view.pool, jnp.asarray(view.tables),
                         jnp.asarray(view.lengths), toks)
-                    # the step returns a whole new pool (not donated)
+                    # the step writes the whole pool it returns (in place
+                    # of the donated one)
                     self.kv.count_copy(pool.nbytes)
+                    # commit while the step runs: the buffer takes the
+                    # returned pool before any other host code runs
+                    self.kv.commit_decode(view, pool)
                     with tr.span("engine.sync", op="serve"):
                         logits = np.asarray(logits)
-                    self.kv.commit_decode(view, pool)
             except OutOfMemory:
                 # the pool shrank under us (failover mid-decode): the
                 # round's working set can no longer be materialized —

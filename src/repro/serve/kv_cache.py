@@ -21,12 +21,25 @@ first), written with one ``write_many`` burst.  Pages the slab opens
 are fresh, so nothing is read for them; only a slab that starts
 mid-page reads that one page, whose live tokens the same call keeps.
 
+A decode round runs in place on the onboard pool.  When the round's
+pages co-reside onboard, :meth:`PagedKVStore.decode_view` faults them
+in with one batched call and hands the compiled step the onboard pool
+itself, with page tables in onboard-slot space; the step donates the
+pool and writes the new token's K/V into the tail pages' slots, and
+:meth:`PagedKVStore.commit_decode` installs the pool it returns.
+Nothing is gathered and nothing is scattered.  Only a round whose pages
+outgrow the onboard tier gathers them (``read_many`` waves, padded to a
+power of two) and writes the tail pages back with ``write_many``.
+
 Every array op the KV path runs on HBM — gathers, pads, stacks, the
-packed slab, the onboard pool's scatters (the whole pool: the update is
-not donated) and the pool the decode step returns — adds the bytes it
-writes to the host registry's ``kv.hbm_copy_bytes`` counter, computed
-from shapes at the call.  With tracing on, ``kv.append``, ``kv.view``
-and ``kv.commit`` spans cover the store's three data-path calls.
+packed slab, the onboard pool's eager scatters (each writes a whole new
+pool) and the pool the decode step returns — adds the bytes it writes
+to the host registry's ``kv.hbm_copy_bytes`` counter, computed from
+shapes at the call.  With tracing on, ``kv.append``, ``kv.view`` and
+``kv.commit`` spans cover the store's three data-path calls; each
+``kv.view`` carries ``in_place`` (1 or 0), and the registry counts the
+rounds of each kind as ``kv.decode_in_place`` and
+``kv.decode_gathered``.
 """
 
 from __future__ import annotations
@@ -51,6 +64,10 @@ HBM_COPY_BYTES = "kv.hbm_copy_bytes"
 #: pages read first because the slab starts in the middle of one
 APPEND_PAGES_PACKED = "kv.append_pages_packed"
 APPEND_PAGES_READ = "kv.append_pages_read"
+#: counters of the decode rounds run in place on the onboard pool, and
+#: of those whose pages outgrew it and were gathered
+DECODE_IN_PLACE = "kv.decode_in_place"
+DECODE_GATHERED = "kv.decode_gathered"
 
 
 @functools.partial(jax.jit, static_argnames=("n_pages", "page_tokens"))
@@ -87,23 +104,28 @@ class SeqPages:
 class DecodeView:
     """One decode round's batched view over the paged pool.
 
-    ``pool`` is the union of the active sequences' pages materialized
-    onboard with ONE coalesced ``read_many`` burst (padded with zero
-    pages to a power of two so the compiled step sees few distinct pool
-    shapes); ``tables`` indexes INTO THE POOL (not logical page ids), so
-    a compiled paged-attention step can consume it directly.  ``pages``
-    is the round's touched-page list — exactly what rides the
-    schedule_prefetch / meter accounting so modeled link traffic
-    reconciles with ``fm.op_bytes()``.
+    ``pool`` is the array the compiled step reads and writes, and
+    ``tables`` index into it (not logical page ids).  ``in_place``: the
+    round's pages co-reside onboard, ``pool`` is the LinkedBuffer's
+    onboard pool itself and ``tables`` hold onboard slots; the step
+    donates it, and the commit installs the pool the step returns.
+    Otherwise ``pool`` is the union of the active sequences' pages,
+    gathered with ``read_many``'s waves and padded with zero pages to a
+    power of two (few distinct pool shapes), and the commit writes the
+    tail pages back with ``write_many``.  ``pages`` is the round's
+    touched-page list — exactly what rides the schedule_prefetch /
+    meter accounting so modeled link traffic reconciles with
+    ``fm.op_bytes()``.
     """
 
     sids: List[int]
-    pool: jax.Array          # [P_pad, L, 2, T, KV, hd]
+    pool: jax.Array          # [P, L, 2, T, KV, hd]
     tables: np.ndarray       # [B, MP] int32 pool indices (-1 pad)
     lengths: np.ndarray      # [B] int32 tokens stored (pre-step)
-    pages: List[int]         # union logical pages backing pool[:n]
+    pages: List[int]         # the round's union of logical pages
     tail_pages: List[int]    # per-sequence logical tail page
     tail_index: List[int]    # per-sequence pool index of the tail page
+    in_place: bool           # pool is the onboard pool itself
 
 
 class PagedKVStore:
@@ -352,59 +374,77 @@ class PagedKVStore:
 
     def decode_view(self, sids: List[int], max_pages: int) -> DecodeView:
         """Build one round's batched decode view: tail pages guaranteed,
-        the union of the active sequences' pages faulted onboard with ONE
-        coalesced ``read_many`` burst (metered exactly like any other
-        batched access — hits for onboard-resident pages, link charges
-        only for LMB misses, waves when the union exceeds onboard
-        capacity), and page tables rewritten into pool-index space for
-        the compiled step.  Active sequences must not share a tail page
-        (the engine never forks a mid-flight sequence)."""
-        with self.buf.trace.span("kv.view", op="demand", batch=len(sids)):
-            return self._decode_view(sids, max_pages)
+        the union of the active sequences' pages faulted onboard with
+        ONE batched fault (metered exactly like any other batched
+        access — hits for onboard-resident pages, link charges only for
+        LMB misses), and page tables rewritten into the step's pool
+        index space.  When the union co-resides onboard the view is the
+        onboard pool itself, in onboard-slot space; otherwise it is the
+        union gathered with ``read_many``'s waves.  The ``kv.view`` span
+        carries ``in_place``.  Active sequences must not share a tail
+        page (the engine never forks a mid-flight sequence)."""
+        with self.buf.trace.span("kv.view", op="demand",
+                                 batch=len(sids)) as args:
+            view = self._decode_view(sids, max_pages)
+            if args is not None:
+                args["in_place"] = int(view.in_place)
+            return view
 
     def _decode_view(self, sids: List[int], max_pages: int) -> DecodeView:
         for sid in sids:
             self.ensure_tail_page(sid)
         tables, lengths = self.page_tables(sids, max_pages)
-        union: List[int] = []
-        index: Dict[int, int] = {}
-        for sid in sids:
-            for p in self._seqs[sid].pages:
-                if p not in index:
-                    index[p] = len(union)
-                    union.append(p)
-        pool = self.buf.read_many(union)       # [n, L, 2, T, KV, hd]
-        n = len(union)
-        # pad with zero pages to a power of two: the compiled decode step
-        # sees O(log) distinct pool shapes instead of one per round
-        cap = max(8, 1 << (n - 1).bit_length())
-        if cap > n:
-            pool = jnp.concatenate(
-                [pool, jnp.zeros((cap - n,) + self.page_shape,
-                                 pool.dtype)])
-            self.count_copy(pool.nbytes // cap * (2 * cap - n))
-        pool_tables = np.full_like(tables, -1)
-        mapped = tables >= 0
-        pool_tables[mapped] = [index[p] for p in tables[mapped].tolist()]
+        union = list(dict.fromkeys(
+            p for sid in sids for p in self._seqs[sid].pages))
         tail_pages = [
             self._seqs[sid].pages[self._seqs[sid].length //
                                   self.page_tokens]
             for sid in sids]
-        tail_index = [index[p] for p in tail_pages]
+        slots = self.buf.onboard_slots(union, written=tail_pages)
+        in_place = slots is not None
+        if in_place:
+            pool = self.buf.onboard_array
+            self.metrics.inc(DECODE_IN_PLACE)
+        else:
+            pool = self.buf.read_many(union)   # [n, L, 2, T, KV, hd]
+            slots = range(len(union))
+            n = len(union)
+            # pad with zero pages to a power of two: the compiled step
+            # sees O(log) distinct pool shapes instead of one per round
+            cap = max(8, 1 << (n - 1).bit_length())
+            if cap > n:
+                pool = jnp.concatenate(
+                    [pool, jnp.zeros((cap - n,) + self.page_shape,
+                                     pool.dtype)])
+                self.count_copy(pool.nbytes // cap * (2 * cap - n))
+            self.metrics.inc(DECODE_GATHERED)
+        index = dict(zip(union, slots))
+        pool_tables = np.full_like(tables, -1)
+        mapped = tables >= 0
+        pool_tables[mapped] = [index[p] for p in tables[mapped].tolist()]
         return DecodeView(sids=list(sids), pool=pool,
                           tables=pool_tables,
                           lengths=lengths, pages=union,
-                          tail_pages=tail_pages, tail_index=tail_index)
+                          tail_pages=tail_pages,
+                          tail_index=[index[p] for p in tail_pages],
+                          in_place=in_place)
 
     def commit_decode(self, view: DecodeView, pool: jax.Array) -> None:
-        """Write one decode round's results back: only the tail pages
-        changed (the step scatters the new token's K/V there), so ONE
-        ``write_many`` burst covers the whole batch, and each sequence
-        advances by the token it just stored."""
+        """Take one decode round's results: ``pool`` is what the step
+        returned, with the new token's K/V in each tail page.  In place,
+        it becomes the onboard pool with ONE ``replace_onboard`` call
+        (the tail pages marked dirty; nothing copied); a gathered view's
+        tail rows go back with ONE ``write_many`` burst.  Each sequence
+        advances by the token it just stored.  Call it as soon as the
+        step is dispatched: the buffer then never holds a donated array
+        while other host code runs."""
         with self.buf.trace.span("kv.commit", op="demand",
                                  batch=len(view.sids)):
-            rows = pool[np.asarray(view.tail_index, np.int64)]
-            self.count_copy(rows.nbytes)
-            self.buf.write_many(view.tail_pages, rows)
+            if view.in_place:
+                self.buf.replace_onboard(pool, view.tail_pages)
+            else:
+                rows = pool[np.asarray(view.tail_index, np.int64)]
+                self.count_copy(rows.nbytes)
+                self.buf.write_many(view.tail_pages, rows)
             for sid in view.sids:
                 self._seqs[sid].length += 1

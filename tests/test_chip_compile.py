@@ -76,8 +76,12 @@ def test_decode_step_paged_compiles_for_v5e(one_chip, monkeypatch):
     B, P, T, MP = 8, 128, 32, 32
     pool = _sds((P, cfg.num_layers, 2, T, cfg.num_kv_heads, cfg.head_dim_),
                 jnp.bfloat16, one_chip)
-    compiled = jax.jit(model.decode_step_paged).lower(
+    # as the engine jits it: the pool donated, so the step's output pool
+    # takes the donated buffer
+    compiled = jax.jit(model.decode_step_paged, donate_argnums=(1,)).lower(
         params, pool, _sds((B, MP), jnp.int32, one_chip),
         _sds((B,), jnp.int32, one_chip),
         _sds((B, 1), jnp.int32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "input_output_alias" in text

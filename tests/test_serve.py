@@ -8,16 +8,18 @@ import pytest
 
 from repro.configs.base import get_config
 from repro.core import system_for
+from repro.core.metrics import Metrics
 from repro.models import build_model
 from repro.models.flags import Flags
 from repro.serve import EngineConfig, ServeEngine, SubmitSpec
-from repro.serve.kv_cache import PagedKVStore
+from repro.serve.kv_cache import (DECODE_GATHERED, DECODE_IN_PLACE,
+                                  PagedKVStore)
 
 
-def fresh_system(pool_gib=1):
+def fresh_system(pool_gib=1, metrics=None):
     """The serve stack is constructed through the client API."""
     return system_for("tpu0", host_id="h0", pool_gib=pool_gib,
-                      page_bytes=4096)
+                      page_bytes=4096, metrics=metrics)
 
 
 @pytest.fixture(scope="module")
@@ -32,11 +34,12 @@ def make_engine(served, **kw):
     cfg, model, params = served
     qos = kw.pop("qos", None)
     clock = kw.pop("clock", None)
+    metrics = kw.pop("metrics", None)
     defaults = dict(decode_slots=2, max_seq_len=64, page_tokens=8,
                     onboard_pages=8, prefill_bucket=16)
     defaults.update(kw)
-    return ServeEngine(model, params, fresh_system(), EngineConfig(
-        **defaults), qos=qos, clock=clock)
+    return ServeEngine(model, params, fresh_system(metrics=metrics),
+                       EngineConfig(**defaults), qos=qos, clock=clock)
 
 
 def test_requests_complete(served):
@@ -418,49 +421,155 @@ def test_gather_seq_trims_to_length(served):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(data))
 
 
-def test_paged_decode_serves_identical_tokens(served):
+#: prompts of the token-identity cases: 1 to 3 pages of 8 tokens each
+IDENTITY_PROMPTS = [np.random.default_rng(7).integers(1, 100, n)
+                    .astype(np.int32) for n in (5, 13, 20, 9, 17)]
+
+
+def serve_prompts(served, prompts, **kw):
+    """Serve ``prompts`` (6 new tokens each) to the end; returns the
+    token streams and the engine."""
+    eng = make_engine(served, metrics=Metrics(), **kw)
+    rids = [eng.submit(SubmitSpec(prompt=p, max_new_tokens=6))
+            for p in prompts]
+    eng.run(300)
+    assert all(eng.requests[r].state == "done" for r in rids)
+    return [eng.requests[r].out_tokens for r in rids], eng
+
+
+@pytest.fixture(scope="module")
+def dense_tokens(served):
+    """The dense slot-cache path's token streams: the reference."""
+    return serve_prompts(served, IDENTITY_PROMPTS, paged_decode=False)[0]
+
+
+@pytest.mark.parametrize("path", ["in_place", "gathered", "dense"])
+def test_paged_decode_serves_identical_tokens(served, dense_tokens, path):
     """The tentpole contract: with paged_decode on (the default), every
     decode round runs ONE batched paged-attention step against the
     paged pool, and the emitted token streams are byte-identical to the
-    dense slot-cache path."""
+    dense slot-cache path — whether the round runs in place on the
+    onboard pool (8 pages hold both slots' pages), or its pages outgrow
+    the onboard tier (3 pages) and it runs on a gathered copy."""
     from repro.kernels import ops as kops
 
-    rng = np.random.default_rng(7)
-    prompts = [rng.integers(1, 100, n).astype(np.int32)
-               for n in (5, 13, 20, 9, 17)]
-
-    def serve(paged):
-        eng = make_engine(served, paged_decode=paged, trace=paged)
-        rids = [eng.submit(SubmitSpec(prompt=p, max_new_tokens=6))
-                for p in prompts]
-        eng.run(300)
-        toks = [eng.requests[r].out_tokens for r in rids]
-        assert all(eng.requests[r].state == "done" for r in rids)
-        return toks, eng
-
-    dense_toks, dense_eng = serve(False)
+    kw = {"in_place": dict(trace=True),
+          "gathered": dict(trace=True, onboard_pages=3),
+          "dense": dict(paged_decode=False)}[path]
     before = kops.paged_attention_decode_traces()
-    paged_toks, paged_eng = serve(True)
-    assert paged_toks == dense_toks              # byte-identical streams
+    toks, eng = serve_prompts(served, IDENTITY_PROMPTS, **kw)
+    assert toks == dense_tokens                  # byte-identical streams
+    in_place = eng.metrics.counter(DECODE_IN_PLACE)
+    gathered = eng.metrics.counter(DECODE_GATHERED)
+    if path == "dense":
+        assert eng.paged_rounds == 0
+        assert eng.stats()["decode_path"] == "dense"
+        assert in_place == gathered == 0
+        return
     # the paged kernel path actually served the rounds
-    assert dense_eng.paged_rounds == 0
-    assert paged_eng.paged_rounds > 0
+    assert eng.paged_rounds > 0
     assert kops.paged_attention_decode_traces() > before
-    assert paged_eng.stats()["decode_path"] == "paged"
-    assert dense_eng.stats()["decode_path"] == "dense"
-    # ...and left its span in the trace
-    names = [s.name for s in paged_eng.trace.spans()]
-    assert "decode.paged" in names
+    assert eng.stats()["decode_path"] == "paged"
+    assert in_place + gathered == eng.paged_rounds
+    # ...and left its spans in the trace, each view saying how it ran
+    views = [s.args["in_place"] for s in eng.trace.spans()
+             if s.name == "kv.view"]
+    assert "decode.paged" in {s.name for s in eng.trace.spans()}
+    assert (sum(views), len(views)) == (in_place, eng.paged_rounds)
+    if path == "in_place":
+        assert gathered == 0
+    else:
+        assert gathered > 0
     # the dense handoff cache is retired on the paged path
-    assert all(r._cache is None for r in paged_eng.requests.values())
+    assert all(r._cache is None for r in eng.requests.values())
+    eng.kv.buf.check_invariants()
+
+
+def test_in_place_round_installs_the_step_output(served, monkeypatch):
+    """After an in-place round the buffer holds the array the step
+    returned, the onboard pool it donated is deleted, the buffer's
+    invariants hold, and each tail page reads back dirty with the new
+    token's K/V: the same bytes a gathered round stores."""
+    def two_prefilled(**kw):
+        eng = make_engine(served, metrics=Metrics(), **kw)
+        for n in (5, 13):
+            eng.submit(SubmitSpec(prompt=np.arange(1, n + 1),
+                                  max_new_tokens=6))
+        eng.step()                      # both prefills, a round
+        return eng
+
+    eng = two_prefilled()
+    ref = two_prefilled()
+    monkeypatch.setattr(ref.kv.buf, "onboard_slots", lambda *a, **k: None)
+    seen = {}
+    step = eng._paged_fn
+
+    def spy(params, pool, *args):
+        logits, out = step(params, pool, *args)
+        seen.update(donated=pool, returned=out)
+        return logits, out
+    eng._paged_fn = spy
+    sids = [r.seq_id for r in eng.active.values()]
+    pos = [eng.kv.seq(s).length for s in sids]  # where the token lands
+    eng.step()
+    ref.step()
+
+    buf = eng.kv.buf
+    assert eng.metrics.counter(DECODE_IN_PLACE) == 2
+    assert ref.metrics.counter(DECODE_GATHERED) == 1
+    assert buf.onboard_array is seen["returned"]
+    assert seen["donated"].is_deleted()
+    buf.check_invariants()
+    pt = eng.kv.page_tokens
+    for sid, at in zip(sids, pos):
+        tail = eng.kv.seq(sid).pages[at // pt]
+        assert buf._pages[tail].dirty
+        got = np.asarray(buf.read(tail))
+        want = np.asarray(ref.kv.buf.read(ref.kv.seq(sid).pages[at // pt]))
+        np.testing.assert_array_equal(got, want)
+        assert np.any(got[:, :, at % pt] != 0)  # the new token's K/V
+
+
+@pytest.mark.parametrize("onboard_pages", [8, 4])
+def test_in_place_rounds_count_hits_like_gathered_rounds(
+        served, monkeypatch, onboard_pages):
+    """The onboard tier's hit and miss counters over a short run are the
+    same whether its rounds run in place or are all gathered, as every
+    round was before the in-place path — with the working set inside
+    the onboard tier (8 pages), and spilling past it (4 pages, where
+    only some rounds' pages fit)."""
+    def counters(gather_all):
+        eng = make_engine(served, metrics=Metrics(),
+                          onboard_pages=onboard_pages)
+        buf = eng.kv.buf
+        if gather_all:
+            monkeypatch.setattr(buf, "onboard_slots", lambda *a, **k: None)
+        tier = buf.metrics.tier(buf.name, "onboard")
+        hits, misses = tier.hits, tier.misses   # the tier's own registry
+        rids = [eng.submit(SubmitSpec(prompt=p, max_new_tokens=6))
+                for p in IDENTITY_PROMPTS]
+        eng.run(300)
+        rounds = (eng.metrics.counter(DECODE_IN_PLACE),
+                  eng.metrics.counter(DECODE_GATHERED))
+        return ([eng.requests[r].out_tokens for r in rids],
+                tier.hits - hits, tier.misses - misses), rounds
+
+    in_place, (n_in_place, n_gathered) = counters(False)
+    gathered, (n_none, _) = counters(True)
+    assert n_in_place > 0 and n_none == 0
+    assert (n_gathered > 0) == (onboard_pages == 4)
+    assert in_place[1] > 0 and in_place[2] > 0    # fresh pages miss too
+    assert in_place == gathered
 
 
 def test_paged_round_spans_nest_and_count_hbm_copies(served):
     """With tracing on, a paged round's spans nest as the chip path
     runs: decode.paged under serve.round, kv.view under it, the view's
-    read_many burst under that, and every executor op under a
-    LinkedBuffer call.  The round's kv.hbm_copy_bytes is the bytes of
-    its HBM array ops, by hand from the shapes."""
+    onboard_slots fault under that, the commit's replace_onboard under
+    kv.commit, which ends before the round waits on the logits, and
+    every executor op under a LinkedBuffer call.  The round's
+    kv.hbm_copy_bytes is the bytes of its HBM array ops, by hand from
+    the shapes."""
     from repro.serve.kv_cache import HBM_COPY_BYTES
 
     eng = make_engine(served, trace=True)       # 2 slots, 8 onboard pages
@@ -481,11 +590,19 @@ def test_paged_round_spans_nest_and_count_hbm_copies(served):
     assert dec.args == {"batch": 2, "pages": 3, "pool": 8}
     (view,) = [s for s in spans[mark:] if s.name == "kv.view"]
     assert view.parent_id == dec.span_id
-    assert [parent(s) for s in spans[mark:]
-            if s.name == "lmb.read_many"] == ["kv.view"]
+    assert view.args == {"batch": 2, "in_place": 1}
+    for name, under in (("lmb.onboard_slots", "kv.view"),
+                        ("lmb.replace_onboard", "kv.commit")):
+        assert [parent(s) for s in spans[mark:] if s.name == name] == [
+            under]
+    assert not [s for s in spans[mark:]
+                if s.name in ("lmb.read_many", "lmb.write_many")]
     for name in ("engine.sync", "kv.commit"):
         assert [parent(s) for s in spans[mark:] if s.name == name] == [
             "decode.paged"]
+    (commit,) = [s for s in spans[mark:] if s.name == "kv.commit"]
+    (sync,) = [s for s in spans[mark:] if s.name == "engine.sync"]
+    assert commit.t0 + commit.dur <= sync.t0
     assert {parent(s) for s in spans[mark:]
             if s.name in ("engine.emit", "engine.tail")} == {"serve.round"}
 
@@ -499,11 +616,10 @@ def test_paged_round_spans_nest_and_count_hbm_copies(served):
     assert all(any(n.startswith("lmb.") for n in ancestors(s))
                for s in execs)
 
-    # gather of the 3-page union, 5 zero pages and the padded pool of 8,
-    # the 8-page pool the step returns, the 2 tail rows and their
-    # gather in write_many, then the scatter over all 8 onboard pages
+    # in place, the round writes one array on HBM: the step's pool of
+    # all 8 onboard pages, in place of the one it donated
     pb = eng.kv.buf.page_bytes
-    assert copied == pb * (3 + 5 + 8 + 8 + 2 + 2 + 8)
+    assert copied == pb * 8
     assert rnd.args["hbm_copy_bytes"] == copied
 
 
@@ -536,7 +652,8 @@ def test_paged_decode_spills_past_onboard(served):
     """Paged decode with a working set far beyond the onboard tier: the
     DecodeView's coalesced read bursts wave through onboard capacity and
     requests still complete (the capacity thesis on the new data path)."""
-    eng = make_engine(served, decode_slots=4, onboard_pages=4)
+    eng = make_engine(served, decode_slots=4, onboard_pages=4,
+                      metrics=Metrics())
     assert eng._use_paged
     rng = np.random.default_rng(8)
     rids = [eng.submit(SubmitSpec(prompt=rng.integers(0, 100, 20),
@@ -546,3 +663,5 @@ def test_paged_decode_spills_past_onboard(served):
     assert all(eng.requests[r].state == "done" for r in rids)
     c = eng.kv.buf.metrics.tier(eng.kv.buf.name, "onboard")
     assert c.misses > 0              # spill traffic actually happened
+    # the rounds whose pages outgrew the tier ran on a gathered copy
+    assert eng.metrics.counter(DECODE_GATHERED) > 0
